@@ -82,9 +82,9 @@ def run(write: bool = True, iters: int | None = None
     n, m = ((1 << 20, 1 << 13) if on_tpu else (1 << 12, 1 << 9))
     keys = jnp.asarray(np.sort(rng.integers(0, 1 << 40, n)))
     probes = jnp.asarray(rng.integers(0, 1 << 40, m))
-    grid = {
-        "block_m": [128, 256, 512],
-        "block_n": [1024, 2048, 4096] if on_tpu else [512, 1024, 2048],
+    grid = {  # whole (8, 128) int32 tiles
+        "block_m": [1024, 2048, 4096] if on_tpu else [1024, 2048],
+        "block_n": [1024, 2048, 4096] if on_tpu else [1024, 2048],
     }
     cfg, us, r = _sweep(
         "semijoin_probe",
@@ -100,8 +100,8 @@ def run(write: bool = True, iters: int | None = None
     lo = jnp.asarray(rng.integers(0, 1000, n).astype(np.int32))
     hi = lo + jnp.asarray(rng.integers(0, 4, n).astype(np.int32))
     grid = {
-        "block_m": [128, 256, 512],
-        "block_n": [512, 1024, 2048] if on_tpu else [256, 512, 1024],
+        "block_m": [1024, 2048, 4096] if on_tpu else [1024, 2048],
+        "block_n": [1024, 2048, 4096] if on_tpu else [1024, 2048],
     }
     cfg, us, r = _sweep(
         "relalg_expand",
@@ -117,7 +117,7 @@ def run(write: bool = True, iters: int | None = None
     vals = jnp.asarray(rng.integers(0, 1 << 20, (n, 3)).astype(np.int32))
     dest = jnp.asarray(rng.integers(0, w, n).astype(np.int32))
     valid = jnp.asarray(rng.random(n) > 0.1)
-    grid = {"block_n": [128, 256, 512]}
+    grid = {"block_n": [1024, 2048, 4096] if on_tpu else [1024, 2048]}
     cfg, us, r = _sweep(
         "relalg_bucket",
         lambda **c: partial(bucket_by_dest_pallas, n_dest=w,

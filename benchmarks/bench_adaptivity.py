@@ -218,10 +218,15 @@ def run_parallel_mode_sharded(n_devices: int = 8
     queries on the shard-local route must sustain >= 2x the throughput of
     the same queries on the distributed all_to_all path, with zero wire
     cells.  Spawns the forced-8-device subprocess and reads back
-    ``artifacts/parallel_mode_sharded.json``."""
+    ``artifacts/parallel_mode_sharded.json``.
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu`` in its own env): it
+    rehearses the mesh on forced host devices, so its rows are CPU rows,
+    and it never competes for an accelerator the parent holds."""
     root = Path(__file__).resolve().parent.parent
     env = {
         **os.environ,
+        "JAX_PLATFORMS": "cpu",
         # appended last: XLA flag parsing is last-wins, so the forced count
         # beats any same flag already exported (the child asserts it took)
         "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +

@@ -188,10 +188,15 @@ def _skew_sharded_child(out_path: str = _SKEW_ARTIFACT, n_workers: int = 8,
 def run_skew_sharded(n_devices: int = 8) -> list[tuple[str, float, str]]:
     """ISSUE 6 acceptance on the mesh: with a Zipf-skewed (exponent 1.4)
     workload on 8 devices, directory placement must deliver >= 1.5x the qps
-    of hash placement and cut the max/mean shard-load ratio >= 2x."""
+    of hash placement and cut the max/mean shard-load ratio >= 2x.
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu`` in its own env): it
+    rehearses the mesh on forced host devices, so its rows are CPU rows,
+    and it never competes for an accelerator the parent holds."""
     root = Path(__file__).resolve().parent.parent
     env = {
         **os.environ,
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                       f" --xla_force_host_platform_device_count={n_devices}"),
         "PYTHONPATH": os.pathsep.join(

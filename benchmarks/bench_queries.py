@@ -255,10 +255,15 @@ def run_sharded(n_devices: int = 8) -> list[tuple[str, float, str]]:
     ``artifacts/sharded_queries.json``: queries/s and total comm cells for
     the sharded and single-device engines, plus post-warmup recompiles
     (must be zero).  Comm cells must match bit-for-bit — the collectives
-    change where bytes move, not how many."""
+    change where bytes move, not how many.
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu`` in its own env): it
+    rehearses the mesh on forced host devices, so its rows are CPU rows,
+    and it never competes for an accelerator the parent holds."""
     root = Path(__file__).resolve().parent.parent
     env = {
         **os.environ,
+        "JAX_PLATFORMS": "cpu",
         # appended last: XLA flag parsing is last-wins, so the forced count
         # beats any same flag already exported (the child asserts it took)
         "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
@@ -393,10 +398,15 @@ def run_subject_star_sharded(n_devices: int = 8
     ``artifacts/subject_star_sharded.json``: queries/s on the fused
     zero-collective ``mesh-local-main`` route vs the same queries with the
     chain disabled, host syncs per warm query (must be exactly 1) and
-    post-warmup recompiles (must be zero)."""
+    post-warmup recompiles (must be zero).
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu`` in its own env): it
+    rehearses the mesh on forced host devices, so its rows are CPU rows,
+    and it never competes for an accelerator the parent holds."""
     root = Path(__file__).resolve().parent.parent
     env = {
         **os.environ,
+        "JAX_PLATFORMS": "cpu",
         # appended last: XLA flag parsing is last-wins (child asserts)
         "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                       f" --xla_force_host_platform_device_count={n_devices}"),

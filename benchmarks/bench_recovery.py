@@ -177,10 +177,15 @@ def _recovery_child(out_path: str = _ARTIFACT, n_workers: int = 8,
 def run_recovery_sharded(n_devices: int = 8) -> list[tuple[str, float, str]]:
     """ISSUE 7 acceptance on the mesh: one shard failed mid-workload keeps
     every answer bit-identical over the demoted route with zero recompiles,
-    and a restarted master replays to PI-fingerprint parity."""
+    and a restarted master replays to PI-fingerprint parity.
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu`` in its own env): it
+    rehearses the mesh on forced host devices, so its rows are CPU rows,
+    and it never competes for an accelerator the parent holds."""
     root = Path(__file__).resolve().parent.parent
     env = {
         **os.environ,
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                       f" --xla_force_host_platform_device_count={n_devices}"),
         "PYTHONPATH": os.pathsep.join(

@@ -136,10 +136,15 @@ def _serving_child(out_path: str = _ARTIFACT, n_workers: int = 8,
 
 def run_serving_sharded(n_devices: int = 8) -> list[tuple[str, float, str]]:
     """ISSUE 8 serving rows on the mesh: measured saturation throughput and
-    p50/p99, plus the deterministic 2x-overload shed fraction."""
+    p50/p99, plus the deterministic 2x-overload shed fraction.
+
+    The child runs on the CPU (``JAX_PLATFORMS=cpu`` in its own env): it
+    rehearses the mesh on forced host devices, so its rows are CPU rows,
+    and it never competes for an accelerator the parent holds."""
     root = Path(__file__).resolve().parent.parent
     env = {
         **os.environ,
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                       f" --xla_force_host_platform_device_count={n_devices}"),
         "PYTHONPATH": os.pathsep.join(

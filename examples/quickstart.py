@@ -9,11 +9,13 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 from __future__ import annotations
 
 import repro.core  # noqa: F401  (enables x64 for composite keys)
+from repro.compile_cache import enable_compile_cache
 from repro.core.engine import AdHashEngine
 from repro.data.synthetic_rdf import Workload, lubm_like
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1. generate + bulk-load data (subject-hash partitioning, ~1 second —
     #    the paper's "low startup" claim is the whole point)
     dictionary, triples = lubm_like(n_universities=4)

@@ -8,6 +8,7 @@ from __future__ import annotations
 import time
 
 import repro.core  # noqa: F401
+from repro.compile_cache import enable_compile_cache
 from repro.core.engine import AdHashEngine
 from repro.data.synthetic_rdf import Workload, lubm_like
 
@@ -38,6 +39,7 @@ def sparkline(values, width=48):
 
 
 def main() -> None:
+    enable_compile_cache()
     d, triples = lubm_like(n_universities=4)
     order = ["q1", "q12", "q7", "q2"]  # workload shifts every 20 queries
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.core.adaptive import AdaptiveShardingController
 from repro.data.tokens import zipf_tokens
@@ -25,6 +26,7 @@ from repro.models.model_zoo import build_model
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_smoke_config("qwen1.5-4b")
     model = build_model(cfg)
     params = model.init(jax.random.key(0))

@@ -17,6 +17,7 @@ Run:  PYTHONPATH=src python examples/serve_rdf.py
 from __future__ import annotations
 
 import repro.core  # noqa: F401
+from repro.compile_cache import enable_compile_cache
 from repro.core.engine import AdHashEngine
 from repro.data.synthetic_rdf import Workload, lubm_like
 from repro.runtime.fault_injection import VirtualClock
@@ -59,6 +60,7 @@ def run(rate_qps: float, label: str, n: int = 200) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     print("deterministic serving DES: virtual clock, "
           f"{SVC_S * 1e3:.0f}ms/bucket, batch target {BATCH} "
           f"(upper-bound saturation {BATCH / SVC_S:.0f} qps; the mixed "

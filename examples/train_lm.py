@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.core.adaptive import AdaptiveShardingController
 from repro.checkpoint.checkpoint import CheckpointManager
@@ -27,6 +28,7 @@ from repro.optim.adamw import AdamWConfig, adamw_init
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
     ap.add_argument("--steps", type=int, default=60)

@@ -1,12 +1,12 @@
-"""Version-compat shims shared across the tree (no side effects on import).
+"""Host-side helpers shared across the tree (no side effects on import).
 
 Two definitions live here:
 
 ``shard_map``
     Both the LM model stack (``repro.models``) and the RDF execution
-    substrate (``repro.core.substrate``) wrap per-shard bodies in shard_map;
-    this module is the single definition of the cross-version spelling so
-    the two layers can never drift.
+    substrate (``repro.core.substrate``) wrap per-shard bodies in
+    ``jax.shard_map``; this module is the single place that fixes its
+    flags, so the two layers can never drift.
 
 ``fetch_global`` / ``host_barrier``
     The one way any host-side code materializes a device array, and the
@@ -54,14 +54,10 @@ _barrier_seq: dict[str, int] = {}
 
 def _coordination_client():
     """The jax.distributed coordination-service client, or None when the
-    process never joined a multi-process mesh (or the private module moved
-    across a jax upgrade — callers then fall back to gloo collectives)."""
-    try:
-        from jax._src import distributed
+    process never joined a multi-process mesh."""
+    from jax._src import distributed
 
-        return distributed.global_state.client
-    except Exception:  # pragma: no cover - version skew
-        return None
+    return distributed.global_state.client
 
 
 def _kv_fetch_global(x, client) -> np.ndarray:
@@ -134,11 +130,12 @@ def fetch_global(x) -> np.ndarray:
     for why this is not a gloo allgather)."""
     if isinstance(x, jax.Array) and not x.is_fully_addressable:
         client = _coordination_client()
-        if client is not None:
-            return _kv_fetch_global(x, client)
-        from jax.experimental import multihost_utils  # pragma: no cover
-
-        return np.asarray(multihost_utils.process_allgather(x, tiled=True))
+        if client is None:
+            raise RuntimeError(
+                "array spans processes but this process never joined "
+                "jax.distributed"
+            )
+        return _kv_fetch_global(x, client)
     return np.asarray(x)
 
 
@@ -146,31 +143,16 @@ def host_barrier(tag: str = "barrier", timeout_ms: int = _TIMEOUT_MS) -> None:
     """Block until every process reaches this barrier.
 
     Coordination-service barrier (one-shot ids, so a per-tag lockstep
-    counter makes each use unique); no-op under a single process; gloo
-    ``sync_global_devices`` only as a version-skew fallback."""
+    counter makes each use unique); no-op under a single process."""
     if jax.process_count() <= 1:
         return
     client = _coordination_client()
-    if client is None:  # pragma: no cover - version skew
-        from jax.experimental import multihost_utils
-
-        multihost_utils.sync_global_devices(tag)
-        return
     seq = _barrier_seq.get(tag, 0)
     _barrier_seq[tag] = seq + 1
     client.wait_at_barrier(f"hb/{tag}/{seq}", timeout_ms)
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer releases expose it at the top level with a ``check_vma`` flag;
-    older ones only have ``jax.experimental.shard_map.shard_map`` with the
-    equivalent flag spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with the project's default (no VMA checking)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

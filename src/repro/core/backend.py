@@ -11,13 +11,12 @@ all_to_all send buffers).  This module is the single place that decides
   ``searchsorted``  the plain-jnp path — binary searches and argsorts, the
                     default on CPU/GPU where data-dependent gathers are
                     cheap.
-  ``pallas``        the fused kernels (``repro.kernels.semijoin`` for
-                    probes, ``repro.kernels.relalg_ops`` for the relalg
-                    primitives) — the default on TPU, where the VPU prefers
-                    streaming compares over gathers and scatters.  Off-TPU
-                    the relalg impls run the kernels' fused jnp mirrors
-                    (set ``ADHASH_PALLAS_INTERPRET=1`` to force the real
-                    kernels through the interpreter, as CI does).
+  ``pallas``        the default on TPU: the semijoin probe kernel
+                    (``repro.kernels.semijoin``) inside its work envelope
+                    and the jnp binary search past it; the fused jnp
+                    mirrors of the relalg kernels for the other three
+                    primitives (``repro.kernels.relalg_ops`` says why).
+                    ``resolved_impls`` records every choice.
   ``auto``          resolved once per process to one of the two above.
 
 Implementations self-register via :func:`register_impl`; the providers are
@@ -37,6 +36,7 @@ triggering a per-query recompilation storm.  See DESIGN.md §4.
 """
 from __future__ import annotations
 
+import collections
 import importlib
 from typing import Callable
 
@@ -54,6 +54,8 @@ __all__ = [
     "span_search",
     "quantize_capacity",
     "probe_compile_cache_size",
+    "note_impl",
+    "resolved_impls",
 ]
 
 DATA_PLANE_BACKENDS = ("searchsorted", "pallas")
@@ -125,19 +127,45 @@ def get_impl(op: str, backend: str) -> Callable:
         ) from None
 
 
+# --------------------------------------------------------- dispatch record
+# Which implementation each primitive of the ``pallas`` backend resolved to:
+# the Pallas kernel, or the jnp implementation past the kernel's envelope
+# (or off-TPU).  The choice depends on static shapes only, so it is made and
+# counted once per traced call site — per compiled program, not per run.
+_RESOLVED: collections.Counter = collections.Counter()
+
+
+def note_impl(op: str, impl: str) -> None:
+    _RESOLVED[(op, impl)] += 1
+
+
+def resolved_impls() -> dict[str, dict[str, int]]:
+    """{primitive: {"kernel" | "jnp": traced call sites}} so far."""
+    out: dict[str, dict[str, int]] = {}
+    for (op, impl), n in sorted(_RESOLVED.items()):
+        out.setdefault(op, {})[impl] = n
+    return out
+
+
 # ------------------------------------------------------------------- probes
 # Below this many probe keys the O(N) masked-compare kernel cannot beat two
 # binary searches (a scalar oracle probe would scan the whole shard), so tiny
-# probe blocks stay on searchsorted on every backend.  Static shapes make the
-# choice trace-time; results are identical either way.
+# probe blocks stay on searchsorted on every backend; so does every probe
+# past the kernel's work envelope.  Static shapes make the choice
+# trace-time; results are identical either way.
 _MIN_PALLAS_PROBES = 16
 
 
-def _pallas_probe(keys: jax.Array, probes: jax.Array
-                  ) -> tuple[jax.Array, jax.Array]:
-    from repro.kernels.semijoin.semijoin import semijoin_probe
+def _probe_kernel(backend: str, n_keys: int, n_probes: int):
+    """The semijoin kernel where the pallas backend should run it, else
+    None (the jnp binary search runs)."""
+    if backend != "pallas":
+        return None
+    from repro.kernels.semijoin.semijoin import probe_fits, semijoin_probe
 
-    return semijoin_probe(keys, probes)
+    use = n_probes >= _MIN_PALLAS_PROBES and probe_fits(n_keys, n_probes)
+    note_impl("probe", "kernel" if use else "jnp")
+    return semijoin_probe if use else None
 
 
 def range_search(
@@ -147,8 +175,9 @@ def range_search(
 ) -> tuple[jax.Array, jax.Array]:
     """Match range [lo, hi) of each probe key: side-left / side-right
     ``searchsorted`` — the canonical semi-join probe op.  Both int32."""
-    if backend == "pallas" and probes.shape[0] >= _MIN_PALLAS_PROBES:
-        return _pallas_probe(keys, probes)
+    kernel = _probe_kernel(backend, keys.shape[0], probes.shape[0])
+    if kernel is not None:
+        return kernel(keys, probes)
     lo = jnp.searchsorted(keys, probes, side="left").astype(jnp.int32)
     hi = jnp.searchsorted(keys, probes, side="right").astype(jnp.int32)
     return lo, hi
@@ -163,9 +192,10 @@ def span_search(
     """Side-left insertion points of two probe arrays at once — the
     [lo_key, hi_key) composite-key span form used by range scans (P-index
     ranges, variable predicates)."""
-    if backend == "pallas" and lo_keys.shape[0] >= _MIN_PALLAS_PROBES:
-        m = lo_keys.shape[0]
-        lo_both, _ = _pallas_probe(keys, jnp.concatenate([lo_keys, hi_keys]))
+    m = lo_keys.shape[0]
+    kernel = _probe_kernel(backend, keys.shape[0], 2 * m)
+    if kernel is not None:
+        lo_both, _ = kernel(keys, jnp.concatenate([lo_keys, hi_keys]))
         return lo_both[:m], lo_both[m:]
     lo = jnp.searchsorted(keys, lo_keys, side="left").astype(jnp.int32)
     hi = jnp.searchsorted(keys, hi_keys, side="left").astype(jnp.int32)

@@ -637,6 +637,22 @@ def _block_transpose(axis: str, send: jax.Array, k: int) -> jax.Array:
     return jnp.swapaxes(out, k, k + 1)
 
 
+def _pmax(x: jax.Array, axis: str) -> jax.Array:
+    """``pmax`` of non-negative overflow totals over the mesh axis.
+
+    The TPU compiler lowers only Sum for 64-bit all-reduces, so an int64
+    total is reduced as two int32 words: the max of the high words, then
+    the max of the low words among the shards that hold it.  Exact, and
+    still one all-reduce family on the compiled HLO."""
+    if x.dtype != jnp.int64:
+        return jax.lax.pmax(x, axis)
+    hi = (x >> 31).astype(jnp.int32)
+    lo = (x & 0x7FFFFFFF).astype(jnp.int32)
+    top = jax.lax.pmax(hi, axis)
+    low = jax.lax.pmax(jnp.where(hi == top, lo, -1), axis)
+    return (top.astype(jnp.int64) << 31) | low.astype(jnp.int64)
+
+
 def _global_worker_ids(axis: str, w_local: int) -> jax.Array:
     """Global worker index of each local worker on this shard."""
     d = jax.lax.axis_index(axis)
@@ -680,7 +696,7 @@ def _match_rows_sharded(mesh, axis, store, consts, spec, cap_out, backend):
     def body(store, consts):
         rows, valid, total = dsj.match_rows(store, consts, spec, cap_out,
                                             backend=backend)
-        return rows, valid, jax.lax.pmax(total, axis)
+        return rows, valid, _pmax(total, axis)
 
     return _wrap(body, mesh, axis, (_pw(axis), _PR),
                  (_pw(axis), _pw(axis), _PR))(store, consts)
@@ -692,7 +708,7 @@ def _match_first_sharded(mesh, axis, store, consts, spec, cap_out, backend):
     def body(store, consts):
         cols, valid, total = dsj.match_first(store, consts, spec, cap_out,
                                              backend=backend)
-        return cols, valid, jax.lax.pmax(total, axis)
+        return cols, valid, _pmax(total, axis)
 
     return _wrap(body, mesh, axis, (_pw(axis), _PR),
                  (_pw(axis), _pw(axis), _PR))(store, consts)
@@ -705,7 +721,7 @@ def _project_unique_sharded(mesh, axis, cols, valid, col_idx, cap_proj,
     def body(cols, valid):
         proj, pvalid, n = dsj.project_unique(cols, valid, col_idx, cap_proj,
                                              backend=backend)
-        return proj, pvalid, jax.lax.pmax(n, axis)
+        return proj, pvalid, _pmax(n, axis)
 
     return _wrap(body, mesh, axis, (_pw(axis), _pw(axis)),
                  (_pw(axis), _pw(axis), _PR))(cols, valid)
@@ -731,7 +747,7 @@ def _exchange_hash_sharded(mesh, axis, proj, proj_valid, cap_peer, backend,
             recv = _block_transpose(axis, send, 0)
             recv_valid = _block_transpose(axis, svalid, 0)
             cells = _offdiag_cells(axis, svalid)
-            maxb = jax.lax.pmax(jnp.max(maxw), axis)
+            maxb = _pmax(jnp.max(maxw), axis)
             return recv, recv_valid, cells.astype(jnp.int64), maxb
 
         return _wrap(body, mesh, axis, (_pw(axis), _pw(axis)),
@@ -745,7 +761,7 @@ def _exchange_hash_sharded(mesh, axis, proj, proj_valid, cap_peer, backend,
         recv = _block_transpose(axis, send, 0)
         recv_valid = _block_transpose(axis, svalid, 0)
         cells = _offdiag_cells(axis, svalid)
-        maxb = jax.lax.pmax(jnp.max(maxw), axis)
+        maxb = _pmax(jnp.max(maxw), axis)
         return recv, recv_valid, cells.astype(jnp.int64), maxb
 
     return _wrap(body, mesh, axis, (_pw(axis), _pw(axis), _PR),
@@ -785,8 +801,8 @@ def _probe_and_reply_sharded(mesh, axis, store, recv, recv_valid, consts,
             cand,
             cand_valid,
             cells.astype(jnp.int64),
-            jax.lax.pmax(jnp.max(totals), axis),
-            jax.lax.pmax(jnp.max(maxb), axis),
+            _pmax(jnp.max(totals), axis),
+            _pmax(jnp.max(maxb), axis),
         )
 
     return _wrap(body, mesh, axis, (_pw(axis), _pw(axis), _pw(axis), _PR),
@@ -806,7 +822,7 @@ def _finalize_join_sharded(mesh, axis, rel_cols, rel_valid, cand, cand_valid,
             rel_cols, rel_valid, cand, cand_valid, join_col_rel, probe_col,
             shared_checks, append_cols, cap_out, backend=backend,
         )
-        return cols, valid, jax.lax.pmax(total, axis)
+        return cols, valid, _pmax(total, axis)
 
     return _wrap(body, mesh, axis,
                  (_pw(axis), _pw(axis), _pw(axis), _pw(axis)),
@@ -826,7 +842,7 @@ def _local_probe_join_sharded(mesh, axis, store, rel_cols, rel_valid, consts,
             store, rel_cols, rel_valid, consts, spec, join_col_rel,
             probe_col, shared_checks, append_cols, cap_out, backend=backend,
         )
-        return cols, valid, jax.lax.pmax(total, axis)
+        return cols, valid, _pmax(total, axis)
 
     return _wrap(body, mesh, axis, (_pw(axis), _pw(axis), _pw(axis), _PR),
                  (_pw(axis), _pw(axis), _PR))(
@@ -950,7 +966,7 @@ def _match_first_batch_sharded(mesh, axis, store, consts, spec, cap_out,
     def body(store, consts):
         cols, valid, totals = dsj.match_first_batch(store, consts, spec,
                                                     cap_out, backend=backend)
-        return cols, valid, jax.lax.pmax(totals, axis)
+        return cols, valid, _pmax(totals, axis)
 
     return _wrap(body, mesh, axis, (_pw(axis), _PR),
                  (_pb(axis), _pb(axis), _PR))(store, consts)
@@ -964,7 +980,7 @@ def _project_unique_batch_sharded(mesh, axis, cols, valid, col_idx, cap_proj,
         proj, pvalid, n = dsj.project_unique_batch(
             cols, valid, col_idx, cap_proj, backend=backend
         )
-        return proj, pvalid, jax.lax.pmax(n, axis)
+        return proj, pvalid, _pmax(n, axis)
 
     return _wrap(body, mesh, axis, (_pb(axis), _pb(axis)),
                  (_pb(axis), _pb(axis), _PR))(cols, valid)
@@ -988,7 +1004,7 @@ def _exchange_hash_batch_sharded(mesh, axis, proj, proj_valid, cap_peer,
             recv = _block_transpose(axis, send, 1)
             recv_valid = _block_transpose(axis, svalid, 1)
             cells = _offdiag_cells_batch(axis, svalid)
-            maxb = jax.lax.pmax(jnp.max(maxw, axis=1), axis)
+            maxb = _pmax(jnp.max(maxw, axis=1), axis)
             return recv, recv_valid, cells.astype(jnp.int64), maxb
 
         return _wrap(body, mesh, axis, (_pb(axis), _pb(axis)),
@@ -1003,7 +1019,7 @@ def _exchange_hash_batch_sharded(mesh, axis, proj, proj_valid, cap_peer,
         recv = _block_transpose(axis, send, 1)
         recv_valid = _block_transpose(axis, svalid, 1)
         cells = _offdiag_cells_batch(axis, svalid)
-        maxb = jax.lax.pmax(jnp.max(maxw, axis=1), axis)
+        maxb = _pmax(jnp.max(maxw, axis=1), axis)
         return recv, recv_valid, cells.astype(jnp.int64), maxb
 
     return _wrap(body, mesh, axis, (_pb(axis), _pb(axis), _PR),
@@ -1050,8 +1066,8 @@ def _probe_and_reply_batch_sharded(mesh, axis, store, recv, recv_valid,
             cand,
             cand_valid,
             cells.astype(jnp.int64),
-            jax.lax.pmax(jnp.max(totals, axis=1), axis),
-            jax.lax.pmax(jnp.max(maxb, axis=1), axis),
+            _pmax(jnp.max(totals, axis=1), axis),
+            _pmax(jnp.max(maxb, axis=1), axis),
         )
 
     return _wrap(body, mesh, axis, (_pw(axis), _pb(axis), _pb(axis), _PR),
@@ -1072,7 +1088,7 @@ def _finalize_join_batch_sharded(mesh, axis, rel_cols, rel_valid, cand,
             rel_cols, rel_valid, cand, cand_valid, join_col_rel, probe_col,
             shared_checks, append_cols, cap_out, backend=backend,
         )
-        return cols, valid, jax.lax.pmax(totals, axis)
+        return cols, valid, _pmax(totals, axis)
 
     return _wrap(body, mesh, axis,
                  (_pb(axis), _pb(axis), _pb(axis), _pb(axis)),
@@ -1093,7 +1109,7 @@ def _local_probe_join_batch_sharded(mesh, axis, store, rel_cols, rel_valid,
             store, rel_cols, rel_valid, consts, spec, join_col_rel,
             probe_col, shared_checks, append_cols, cap_out, backend=backend,
         )
-        return cols, valid, jax.lax.pmax(totals, axis)
+        return cols, valid, _pmax(totals, axis)
 
     return _wrap(body, mesh, axis, (_pw(axis), _pb(axis), _pb(axis), _PR),
                  (_pb(axis), _pb(axis), _PR))(

@@ -109,7 +109,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=dict(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ) if not interpret else None,
         interpret=interpret,

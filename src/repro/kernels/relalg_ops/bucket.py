@@ -2,23 +2,19 @@
 
 The reference builds per-destination send buffers with a stable argsort by
 destination plus ``searchsorted`` slicing: O(n log n) and gather-bound.  The
-kernel skips the sort entirely.  For every destination ``d`` (parallel grid
-axis) it streams the input blocks (sequential axis), keeps the running count
-of rows already placed for ``d`` in scratch, and computes each row's slot as
+kernel skips the sort.  For every destination ``d`` (parallel grid axis) it
+streams the input blocks (sequential axis) through SMEM and walks the rows
+in order, carrying the count of rows already placed for ``d``: a row bound
+for ``d`` lands in slot ``count`` and bumps it, so row order within a
+destination is the original input order, bit-identical to the
+stable-argsort reference.  Rows with rank >= cap_peer are counted but not
+placed (dropped, like the reference's clamped slices), and the final count
+is the rows ``d`` wanted — the overflow signal.
 
-  rank_i = carry_d + (#rows j <= i in this block with dest_j == d) - 1
-
-via an in-block prefix sum.  Placement is a masked-compare reduction instead
-of a scatter (TPU has no vector scatter): slot ``s`` of the output block
-accumulates ``sum_i values_i * [rank_i == s]`` — exactly one row matches per
-live slot, rows with rank >= cap_peer match nothing (dropped, like the
-reference's clamped slices).  Row order within a destination is original
-input order, bit-identical to the stable-argsort reference.
-
-VMEM budget: the (block_n, cap_peer) compare plane plus the (cap_peer, k)
-accumulator must fit; the autotuner sweeps ``block_n`` against it.  Like the
-sibling semijoin kernel, blocks are 1-D/2-D untiled — validated in interpret
-mode off-TPU.
+Each column of the send buffer is a lane-dense block of slots that stays
+resident in VMEM across the input axis; a placement rewrites the one
+(1, 128) row holding the slot.  VMEM is k * cap_peer int32 per buffer and
+the scalar walk is n * n_dest steps.
 """
 from __future__ import annotations
 
@@ -29,48 +25,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.relalg_ops._common import cumsum_1d, default_interpret
+from repro.kernels.common import (
+    LANES,
+    TILE,
+    as_rows,
+    check_block,
+    compiler_params,
+    default_interpret,
+    for_each_scalar,
+    round_up,
+)
 from repro.kernels.tuning import block_config
 
 __all__ = ["bucket_by_dest_pallas"]
 
 
-def _kernel(vals_ref, dest_ref, valid_ref, send_ref, cnt_ref, acc_scr, c_scr,
-            *, n_in_blocks: int, block_n: int, cap_peer: int, k: int,
-            pad: int):
+def _kernel(dest_ref, *refs, k: int, in_rows: int, cap_peer: int,
+            n_in_blocks: int, pad: int):
+    val_refs = refs[:k]
+    send_ref, cnt_ref, cnt_scr = refs[k:]
     d = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        c_scr[...] = jnp.zeros_like(c_scr)
+        send_ref[...] = jnp.full(send_ref.shape, pad, jnp.int32)
+        cnt_scr[0] = jnp.int32(0)
 
-    vals = vals_ref[...]  # (block_n, k)
-    m = (valid_ref[...] != 0) & (dest_ref[...] == d)
-    mi = m.astype(jnp.int32)
-    ranks = c_scr[0] + cumsum_1d(mi, block_n) - 1
-    slots = jax.lax.broadcasted_iota(jnp.int32, (block_n, cap_peer), 1)
-    eq = m[:, None] & (ranks[:, None] == slots)  # one-hot placement plane
-    for c in range(k):  # k is tiny and static (payload width)
-        acc_scr[:, c] += jnp.sum(
-            jnp.where(eq, vals[:, c][:, None], 0), axis=0,
-            dtype=acc_scr.dtype,
-        )
-    c_scr[0] += jnp.sum(mi, dtype=jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def one_row(r, l, count):
+        mine = dest_ref[r, l] == d
+
+        @pl.when(mine & (count < cap_peer))
+        def _place():
+            row = pl.ds(count >> 7, 1)  # count // LANES
+            at = lane == (count & (LANES - 1))
+            for c in range(k):
+                cur = send_ref[0, c, row, :]
+                send_ref[0, c, row, :] = jnp.where(at, val_refs[c][r, l], cur)
+
+        return count + mine.astype(jnp.int32)
+
+    cnt_scr[0] = for_each_scalar(in_rows, one_row, cnt_scr[0])
 
     @pl.when(j == n_in_blocks - 1)
     def _final():
-        cnt = c_scr[0]
-        live = jax.lax.broadcasted_iota(jnp.int32, (cap_peer,), 0) < cnt
-        send_ref[0] = jnp.where(
-            live[:, None], acc_scr[...], jnp.asarray(pad, acc_scr.dtype)
-        )
-        cnt_ref[0] = cnt
+        cnt_ref[...] = jnp.full(cnt_ref.shape, cnt_scr[0], jnp.int32)
 
 
 def bucket_by_dest_pallas(
-    values: jax.Array,  # (n, k) payload rows
+    values: jax.Array,  # (n, k) int32 payload rows
     dest: jax.Array,  # (n,) destination per row
     valid: jax.Array,  # (n,)
     n_dest: int,
@@ -85,46 +90,42 @@ def bucket_by_dest_pallas(
     if interpret is None:
         interpret = default_interpret()
     block_n = block_n or block_config("relalg_bucket")["block_n"]
+    check_block("block_n", block_n)
+    if values.dtype != jnp.int32:
+        raise TypeError(f"bucket payload must be int32, got {values.dtype}")
     n, k = values.shape
-    dest32 = dest.astype(jnp.int32)
-    valid32 = valid.astype(jnp.int32)
-
-    n_pad = -(-max(n, 1) // block_n) * block_n
-    if n_pad != n:
-        values = jnp.pad(values, ((0, n_pad - n), (0, 0)))
-        dest32 = jnp.pad(dest32, (0, n_pad - n), constant_values=-1)
-        valid32 = jnp.pad(valid32, (0, n_pad - n))
+    n_pad = round_up(n, block_n)
+    cap_pad = round_up(cap_peer, TILE)
+    cap_rows, in_rows = cap_pad // LANES, block_n // LANES
     grid = (n_dest, n_pad // block_n)
 
-    kernel = functools.partial(
-        _kernel, n_in_blocks=grid[1], block_n=block_n, cap_peer=cap_peer,
-        k=k, pad=pad,
-    )
+    # invalid rows and padding are bound for no destination
+    dest32 = jnp.where(valid, dest, -1).astype(jnp.int32)
+    cols = [as_rows(values[:, c], n_pad, 0) for c in range(k)]
+    in_spec = pl.BlockSpec((in_rows, LANES), lambda d, j: (j, jnp.int32(0)),
+                           memory_space=pltpu.SMEM)
+    zero = lambda: jnp.int32(0)  # noqa: E731  (index maps stay int32)
     send, counts = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, k=k, in_rows=in_rows, cap_peer=cap_peer,
+                          n_in_blocks=grid[1], pad=pad),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, k), lambda d, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda d, j: (j,)),
-            pl.BlockSpec((block_n,), lambda d, j: (j,)),
-        ],
+        in_specs=[in_spec] * (1 + k),
         out_specs=[
-            pl.BlockSpec((1, cap_peer, k), lambda d, j: (d, 0, 0)),
-            pl.BlockSpec((1,), lambda d, j: (d,)),
+            pl.BlockSpec((1, k, cap_rows, LANES),
+                         lambda d, j: (d, zero(), zero(), zero())),
+            pl.BlockSpec((1, 8, LANES), lambda d, j: (d, zero(), zero())),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_dest, cap_peer, k), values.dtype),
-            jax.ShapeDtypeStruct((n_dest,), jnp.int32),
+            jax.ShapeDtypeStruct((n_dest, k, cap_rows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((n_dest, 8, LANES), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((cap_peer, k), values.dtype),
-            pltpu.VMEM((1,), jnp.int32),
-        ],
-        compiler_params=dict(
-            dimension_semantics=("parallel", "arbitrary")
-        ) if not interpret else None,
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=compiler_params(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
-    )(values, dest32, valid32)
+    )(as_rows(dest32, n_pad, -1), *cols)
+    send = jnp.swapaxes(send.reshape(n_dest, k, cap_pad)[:, :, :cap_peer],
+                        1, 2)
+    counts = counts[:, 0, 0]
     slot = jnp.arange(cap_peer, dtype=jnp.int32)
     send_valid = slot[None, :] < counts[:, None]
     max_wanted = (

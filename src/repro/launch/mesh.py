@@ -12,10 +12,16 @@ import jax
 __all__ = ["make_production_mesh", "make_local_mesh", "batch_axes"]
 
 
+def _auto(n_axes: int) -> tuple:
+    """Auto-sharded axes: ``jax.make_mesh`` defaults to Explicit axes, which
+    the model stack's gathers reject."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh() -> jax.sharding.Mesh:
@@ -26,7 +32,8 @@ def make_local_mesh() -> jax.sharding.Mesh:
         if n % m == 0 and n >= m:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def batch_axes(mesh: jax.sharding.Mesh, global_batch: int) -> tuple[str, ...]:

@@ -16,7 +16,8 @@ Two halves:
 ``launch_localhost`` / ``python -m repro.launch``
     A test/bench launcher that spawns N worker processes on localhost, each
     with its own block of ``--xla_force_host_platform_device_count`` fake
-    CPU devices, wires the env protocol (one free coordinator port, dense
+    CPU devices (``JAX_PLATFORMS=cpu`` in each worker's env, so the workers
+    are CPU rehearsals and never compete for an accelerator), wires the env protocol (one free coordinator port, dense
     process ids) and collects per-process exit codes and output.  Workers
     re-enter through ``python -m repro.launch --worker <target>``, which
     initializes jax.distributed *before* importing the target script — the
@@ -234,6 +235,7 @@ def _launch_once(
         penv[ENV_COORDINATOR] = f"127.0.0.1:{port}"
         penv[ENV_NUM_PROCESSES] = str(n_processes)
         penv[ENV_PROCESS_ID] = str(pid)
+        penv["JAX_PLATFORMS"] = "cpu"
         penv["XLA_FLAGS"] = (
             penv.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={devices_per_process}"

@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# single definition of the cross-version shard_map spelling, shared with the
-# RDF execution substrate (repro.core.substrate); re-exported for callers
+# single definition of the project's shard_map flags, shared with the RDF
+# execution substrate (repro.core.substrate); re-exported for callers
 from repro.compat import shard_map
 
 __all__ = [

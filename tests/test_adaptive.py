@@ -64,7 +64,8 @@ def test_adaptive_embed_single_device_matches_plain():
     from repro.models.embedding import adaptive_embed, embed, init_embedding
 
     cfg = get_smoke_config("llama3-8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     key = jax.random.key(0)
     p = init_embedding(key, cfg)
     ids = jnp.asarray(
@@ -87,7 +88,8 @@ def test_adaptive_embed_overflow_reported():
     from repro.models.embedding import adaptive_embed, init_embedding
 
     cfg = get_smoke_config("llama3-8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     p = init_embedding(jax.random.key(0), cfg)
     ids = jnp.asarray(
         np.random.default_rng(1).integers(64, cfg.vocab_size, (2, 16)),
@@ -108,7 +110,8 @@ def test_adaptive_embed_multidevice_subprocess():
         from repro.configs import get_smoke_config
         from repro.models.embedding import adaptive_embed, embed, init_embedding
         cfg = get_smoke_config("llama3-8b")
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = jax.make_mesh((1, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         p = init_embedding(jax.random.key(0), cfg)
         ids = jnp.asarray(np.random.default_rng(0).integers(
             0, cfg.vocab_size, (4, 16)), jnp.int32)

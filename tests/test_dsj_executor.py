@@ -154,3 +154,92 @@ def test_single_pattern_and_constants():
     assert stats.comm_cells == 0
     got = {r[0] for r in rel.to_numpy()}
     assert got == {d.lookup("Lisa"), d.lookup("John"), d.lookup("Fred")}
+
+
+def test_wide_projection_is_cut_to_its_live_prefix(monkeypatch):
+    """A projection far wider than its live values is exchanged at the
+    class of its live prefix; answers, sequential and batched, are those
+    of the brute-force matcher."""
+    from repro.core import executor as ex
+    from repro.core.engine import AdHashEngine
+    from repro.data.synthetic_rdf import lubm_like, lubm_queries
+
+    widths = []
+    cut = ex._live_projection
+
+    def recording(proj, pvalid, n_live, cap_proj):
+        out = cut(proj, pvalid, n_live, cap_proj)
+        widths.append((cap_proj, out[2], proj.shape[-1], out[0].shape[-1]))
+        return out
+
+    monkeypatch.setattr(ex, "_live_projection", recording)
+    d, triples = lubm_like(2, 2, 2, 3, 2, seed=3)
+    eng = AdHashEngine(triples, 4, dictionary=d, adaptive=False,
+                       capacity=1 << 14)
+    rng = np.random.default_rng(3)
+    queries = [t.instantiate(rng) for t in lubm_queries(d).values()]
+    for q in queries:
+        rel, _ = eng.query(q)
+        assert rel.project_to(q.vars).shape[1] == len(q.vars)
+        assert set(map(tuple, rel.project_to(q.vars).tolist())) == \
+            match_query(triples, q), q.name
+    for q, (rel, _) in zip(queries, eng.query_batch(queries + queries)):
+        assert set(map(tuple, rel.project_to(q.vars).tolist())) == \
+            match_query(triples, q), q.name
+    assert widths and all(new == ex._PROJ_FLOOR < old == 1 << 14
+                          and shp_new == new
+                          for old, new, _, shp_new in widths)
+
+
+def test_live_cut_compiles_once_per_class(monkeypatch):
+    """The cut makes the exchange width depend on each instance's live
+    count, so a warm stream of one template compiles the downstream stages
+    once per capacity class its live counts reach, and never again: an
+    instance whose class was seen before compiles nothing, and the classes
+    are bounded by log2(capacity / floor) + 1."""
+    from repro.core import backend as be
+    from repro.core import executor as ex
+    from repro.core.engine import AdHashEngine
+    from repro.core.query import Const
+
+    monkeypatch.setattr(ex, "_PROJ_FLOOR", 4)
+    widths = []
+    cut = ex._live_projection
+
+    def recording(proj, pvalid, n_live, cap_proj):
+        out = cut(proj, pvalid, n_live, cap_proj)
+        widths.append(out[2])
+        return out
+
+    monkeypatch.setattr(ex, "_live_projection", recording)
+    # hubs of out-degree 1 .. 200 over one predicate, so the projection of
+    # ?y after the constant pattern spans several classes
+    knows, likes, rows, obj = 1, 2, [], 10_000
+    for i, degree in enumerate([1, 3, 9, 27, 81, 200]):
+        for _ in range(degree):
+            rows += [(100 + i, knows, obj), (obj, likes, 50_000 + obj % 7)]
+            obj += 1
+    triples = np.array(rows, dtype=np.int64)
+    cap = 1 << 10
+    eng = AdHashEngine(triples, 4, adaptive=False, capacity=cap)
+
+    def hub(i):
+        return Query([TriplePattern(Const(100 + i), Const(knows), Var("y")),
+                      TriplePattern(Var("y"), Const(likes), Var("z"))],
+                     name="hub")
+
+    stream = [hub(i) for i in (0, 1, 0, 2, 3, 1, 4, 5, 3, 5, 0)]
+    seen = set()
+    for pass_ in range(2):
+        for q in stream:
+            widths.clear()
+            before = be.probe_compile_cache_size()
+            rel, st = eng.query(q)
+            assert st.n_dsj == 1
+            assert set(map(tuple, rel.project_to(q.vars).tolist())) == \
+                match_query(triples, q)
+            (width,) = widths
+            if width in seen:
+                assert be.probe_compile_cache_size() == before, (pass_, width)
+            seen.add(width)
+    assert 3 <= len(seen) <= (cap // 4).bit_length()

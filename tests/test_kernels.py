@@ -124,3 +124,23 @@ def test_semijoin_against_triple_store_probe():
     np.testing.assert_array_equal(
         np.asarray(hi_ref - lo_ref), np.asarray(counts)
     )
+
+
+def test_tuned_table_overlays_defaults_and_unreadable_table_raises(
+        tmp_path, monkeypatch):
+    """A platform table overrides block sizes and keeps the envelopes; a
+    table that cannot be read is an error, not a silent fallback."""
+    from repro.kernels.tuning import DEFAULTS, block_config, save_tuned
+
+    monkeypatch.setenv("ADHASH_TUNED_DIR", str(tmp_path / "ok"))
+    save_tuned({"semijoin_probe": {"block_m": 1024}}, platform="cpu")
+    cfg = block_config("semijoin_probe", platform="cpu")
+    assert cfg["block_m"] == 1024
+    assert cfg["max_pairs"] == DEFAULTS["semijoin_probe"]["max_pairs"]
+
+    bad = tmp_path / "bad"
+    bad.mkdir()
+    (bad / "cpu.json").write_text("{not json")
+    monkeypatch.setenv("ADHASH_TUNED_DIR", str(bad))
+    with pytest.raises(RuntimeError, match="unreadable kernel table"):
+        block_config("semijoin_probe", platform="cpu")
