@@ -21,7 +21,7 @@ accounting used by the paper's experiments (Figs. 11b, 13b, 14b).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,21 @@ __all__ = [
 ]
 
 I32MAX = jnp.iinfo(jnp.int32).max
+
+
+def _scope(name: str):
+    """Run a stage's body under ``jax.named_scope(name)``, so the device
+    trace can attribute its operations; the jitted name is unchanged."""
+
+    def wrap(fn):
+        @wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
 
 
 # splitmix64 finalizer — bit-identical to ``partition.hash_ids``; historical
@@ -134,26 +149,30 @@ def match_rows(
     """Local pattern match returning full triple rows (used by IRD).
 
     Returns (rows (W, cap_out, 3), valid, max_total)."""
-    if spec.p_const and spec.s_const:
-        use_po, probed = False, (P, S)
-        lo, hi = match_ranges(store, consts[P], consts[S], use_po=False,
-                              nid=store.n_ids, backend=backend)
-    elif spec.p_const and spec.o_const:
-        use_po, probed = True, (P, O)
-        lo, hi = match_ranges(store, consts[P], consts[O], use_po=True,
-                              nid=store.n_ids, backend=backend)
-    elif spec.p_const:
-        use_po, probed = False, (P,)
-        lo, hi = match_ranges(store, consts[P], jnp.int32(-1), use_po=False,
-                              nid=store.n_ids, backend=backend)
-    else:
-        use_po, probed = False, ()
-        lo, hi = match_ranges(store, jnp.int32(-1), jnp.int32(-1), use_po=False,
-                              nid=store.n_ids, backend=backend)
-    rows, _, valid, totals = gather_rows(
-        store, lo[:, None], hi[:, None], cap_out, use_po=use_po,
-        backend=backend,
-    )
+    with jax.named_scope("probe"):
+        if spec.p_const and spec.s_const:
+            use_po, probed = False, (P, S)
+            lo, hi = match_ranges(store, consts[P], consts[S], use_po=False,
+                                  nid=store.n_ids, backend=backend)
+        elif spec.p_const and spec.o_const:
+            use_po, probed = True, (P, O)
+            lo, hi = match_ranges(store, consts[P], consts[O], use_po=True,
+                                  nid=store.n_ids, backend=backend)
+        elif spec.p_const:
+            use_po, probed = False, (P,)
+            lo, hi = match_ranges(store, consts[P], jnp.int32(-1),
+                                  use_po=False, nid=store.n_ids,
+                                  backend=backend)
+        else:
+            use_po, probed = False, ()
+            lo, hi = match_ranges(store, jnp.int32(-1), jnp.int32(-1),
+                                  use_po=False, nid=store.n_ids,
+                                  backend=backend)
+    with jax.named_scope("expand"):
+        rows, _, valid, totals = gather_rows(
+            store, lo[:, None], hi[:, None], cap_out, use_po=use_po,
+            backend=backend,
+        )
     valid = _residual_mask(rows, valid, spec, consts, probed)
     return rows, valid, jnp.max(totals)
 
@@ -189,8 +208,9 @@ def project_unique(
     Returns (proj (W, cap_proj), proj_valid, max_unique (overflow check))."""
 
     def per_worker(c_w, v_w):
-        u, uv, n = unique_compact(c_w[:, col_idx], v_w, cap_proj, I32MAX,
-                                  backend=backend)
+        with jax.named_scope("unique_compact"):
+            u, uv, n = unique_compact(c_w[:, col_idx], v_w, cap_proj, I32MAX,
+                                      backend=backend)
         return jnp.where(uv, u, -1), uv, n
 
     proj, pvalid, n = jax.vmap(per_worker)(cols, valid)
@@ -221,22 +241,25 @@ def hash_send_buffers(
     def per_worker(p_w, v_w):
         if spec is None:
             dest = (jnp_hash_ids(p_w) % n_workers).astype(jnp.int32)
-            send, svalid, max_wanted = bucket_by_dest(
-                p_w[:, None], dest, v_w, n_workers, cap_peer, backend=backend
-            )
+            with jax.named_scope("bucket_by_dest"):
+                send, svalid, max_wanted = bucket_by_dest(
+                    p_w[:, None], dest, v_w, n_workers, cap_peer, backend=backend
+                )
             return send[..., 0], svalid, max_wanted
         dests, dvalid = spec.value_dests(p_w, v_w, table)  # (F, n) each
         vals = jnp.broadcast_to(p_w[None], dests.shape).reshape(-1)
-        send, svalid, max_wanted = bucket_by_dest(
-            vals[:, None], dests.reshape(-1), dvalid.reshape(-1),
-            n_workers, cap_peer, backend=backend
-        )
+        with jax.named_scope("bucket_by_dest"):
+            send, svalid, max_wanted = bucket_by_dest(
+                vals[:, None], dests.reshape(-1), dvalid.reshape(-1),
+                n_workers, cap_peer, backend=backend
+            )
         return send[..., 0], svalid, max_wanted
 
     return jax.vmap(per_worker)(proj, proj_valid)
 
 
 @partial(jax.jit, static_argnames=("cap_peer", "backend", "spec"))
+@_scope("exchange_hash")
 def exchange_hash(
     proj: jax.Array,  # (W, cap_proj)
     proj_valid: jax.Array,
@@ -303,19 +326,22 @@ def reply_send_buffers(
     w_block, n_send, cap_peer = recv.shape
     flat_vals = recv.reshape(w_block, n_send * cap_peer)
     flat_valid = recv_valid.reshape(w_block, n_send * cap_peer)
-    lo, hi = probe_values(
-        store, consts[P], flat_vals, flat_valid, col=probe_col,
-        nid=store.n_ids, backend=backend,
-    )
-    rows, src, valid, totals = gather_rows(
-        store, lo, hi, cap_flat, use_po=(probe_col == O), backend=backend
-    )
+    with jax.named_scope("probe"):
+        lo, hi = probe_values(
+            store, consts[P], flat_vals, flat_valid, col=probe_col,
+            nid=store.n_ids, backend=backend,
+        )
+    with jax.named_scope("expand"):
+        rows, src, valid, totals = gather_rows(
+            store, lo, hi, cap_flat, use_po=(probe_col == O), backend=backend
+        )
     valid = _residual_mask(rows, valid, spec, consts, probed=(P, probe_col))
     sender = src // cap_peer  # which sender's value produced this row
 
     def per_worker(rows_w, sender_w, valid_w):
-        return bucket_by_dest(rows_w, sender_w, valid_w, n_send, cap_cand,
-                              backend=backend)
+        with jax.named_scope("bucket_by_dest"):
+            return bucket_by_dest(rows_w, sender_w, valid_w, n_send, cap_cand,
+                                  backend=backend)
 
     send, svalid, maxb = jax.vmap(per_worker)(rows, sender, valid)
     return send, svalid, totals, maxb
@@ -323,6 +349,7 @@ def reply_send_buffers(
 
 @partial(jax.jit, static_argnames=("spec", "probe_col", "cap_flat", "cap_cand",
                                    "backend"))
+@_scope("probe_and_reply")
 def probe_and_reply(
     store: ShardedTripleStore,
     recv: jax.Array,  # (W, W_send, cap_peer) received join-column values
@@ -356,6 +383,7 @@ def probe_and_reply(
 @partial(jax.jit, static_argnames=("join_col_rel", "probe_col",
                                    "shared_checks", "append_cols", "cap_out",
                                    "backend"))
+@_scope("finalize_join")
 def finalize_join(
     rel_cols: jax.Array,  # (W, capR, k) current intermediate RS1
     rel_valid: jax.Array,
@@ -383,9 +411,11 @@ def finalize_join(
         skey = key[order]
         scand = cnd[order]
         probe = jnp.where(rvalid, rcols[:, join_col_rel], I32MAX)
-        lo, hi = range_search(skey, probe, backend=backend)
+        with jax.named_scope("probe"):
+            lo, hi = range_search(skey, probe, backend=backend)
         hi = jnp.where(rvalid & (probe != I32MAX), hi, lo)
-        left, pos, valid, total = expand(lo, hi, cap_out, backend=backend)
+        with jax.named_scope("expand"):
+            left, pos, valid, total = expand(lo, hi, cap_out, backend=backend)
         ltuple = rcols[left]
         rtriple = scand[jnp.minimum(pos, scand.shape[0] - 1)]
         for rc, tc in shared_checks:
@@ -425,13 +455,15 @@ def local_probe_join(
     """JoinWithoutCommunication (Algorithm 1 line 7): c2 = pinned subject, so
     every matching triple is already local.  Probe own index directly."""
     vals = rel_cols[:, :, join_col_rel]
-    lo, hi = probe_values(
-        store, consts[P], vals, rel_valid, col=probe_col, nid=store.n_ids,
-        backend=backend,
-    )
-    rows, src, valid, totals = gather_rows(
-        store, lo, hi, cap_out, use_po=(probe_col == O), backend=backend
-    )
+    with jax.named_scope("probe"):
+        lo, hi = probe_values(
+            store, consts[P], vals, rel_valid, col=probe_col, nid=store.n_ids,
+            backend=backend,
+        )
+    with jax.named_scope("expand"):
+        rows, src, valid, totals = gather_rows(
+            store, lo, hi, cap_out, use_po=(probe_col == O), backend=backend
+        )
     valid = _residual_mask(rows, valid, spec, consts, probed=(P, probe_col))
 
     def per_worker(rcols, rows_w, src_w, valid_w):
@@ -602,6 +634,7 @@ def local_probe_join_batch(
 # Pallas provider may re-register a fused per-shard grid pass.
 
 
+@_scope("local_chain")
 def _local_chain_body(store, consts, first_spec, first_keep, steps, caps,
                       backend):
     """match_first + N local probe joins; returns (per-stage rels, totals).
@@ -629,6 +662,7 @@ def _local_chain_body(store, consts, first_spec, first_keep, steps, caps,
     return tuple(rels), jnp.stack(totals)
 
 
+@_scope("local_chain")
 def _local_chain_from_body(store, rel_cols, rel_valid, consts, steps, caps,
                            backend):
     """Suffix restart: re-run ``steps`` seeded from an accepted intermediate.

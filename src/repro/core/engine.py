@@ -17,12 +17,14 @@ flags (§6.3.1) pass through to the distributed executor.
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from repro.compat import fetch_global
 
+from . import trace
 from .backend import quantize_capacity
 from .batcher import WorkloadBatcher
 from .dictionary import Dictionary
@@ -36,6 +38,7 @@ from .placement import resolve_placement
 from .planner import LocalityAwarePlanner, Plan
 from .query import Query, TriplePattern, Var
 from .relation import Relation
+from .substrate import host_fetch
 from .transform import build_redistribution_tree
 
 __all__ = ["AdHashEngine", "EngineReport"]
@@ -59,7 +62,7 @@ class EngineReport:
     n_degraded: int = 0  # shard-local queries (PI hits + main-index chains)
     # demoted to the distributed route by a dark shard (DESIGN §9/§11)
     n_batch_dispatches: int = 0  # batched-pipeline launches (query_batch)
-    wall_time_s: float = 0.0
+    host_syncs: int = 0  # device->host transfers (repro.core.trace.sync)
     history: list[tuple[str, int, float]] = field(default_factory=list)
 
     @property
@@ -199,6 +202,7 @@ class AdHashEngine:
         # query), so the pause is free to enter and converges on exit
         self.adaptivity_paused = False
         self.report = EngineReport()
+        self._sync_mark: int | None = None  # see _syncs_counted
         self.startup_time_s = time.perf_counter() - t0
 
     # ------------------------------------------------------------- streaming
@@ -238,23 +242,21 @@ class AdHashEngine:
         else:
             lo, hi = ranges(self.store, jnp.int32(-1), jnp.int32(-1),
                             use_po=False, nid=self.n_ids, backend=be)
-        return int(jnp.sum(hi - lo))
+        return int(host_fetch(jnp.sum(hi - lo), "count"))
 
     # ------------------------------------------------------------------ query
     def query(self, q: Query) -> tuple[Relation, QueryStats]:
-        t0 = time.perf_counter()
-        # the redistribution tree only feeds the adaptivity machinery
-        tree = (
-            build_redistribution_tree(q, self.stats, self.heuristic)
-            if self.adaptive else None
-        )
+        with self._syncs_counted():
+            return self._query(q)
 
+    def _query(self, q: Query) -> tuple[Relation, QueryStats]:
+        t0 = time.perf_counter()
         # (2) pattern-index hit -> parallel mode over replicas.  While a
         # shard is failed the hit is *demoted*: replica modules would be
         # probed shard-locally — including on the dead shard — so the query
         # runs the distributed route over the main index instead, exact but
         # with communication (DESIGN §9).
-        matches = self.pattern_index.match(tree) if self.adaptive else None
+        tree, matches = self._pi_match(q)
         degraded = matches is not None and self.health.degraded
         if matches is not None and not degraded:
             rel, qstats = self.parallel_exec.execute(
@@ -285,9 +287,32 @@ class AdHashEngine:
         dt = time.perf_counter() - t0
         self.report.n_queries += 1
         self.report.comm_cells += qstats.comm_cells
-        self.report.wall_time_s += dt
         self.report.history.append((qstats.mode, qstats.comm_cells, dt))
         return rel, qstats
+
+    def _pi_match(self, q: Query):
+        """The redistribution tree (it only feeds adaptivity) and its
+        pattern-index match; (None, None) with adaptivity off."""
+        if not self.adaptive:
+            return None, None
+        with trace.span("pi_match"):
+            tree = build_redistribution_tree(q, self.stats, self.heuristic)
+            return tree, self.pattern_index.match(tree)
+
+    @contextmanager
+    def _syncs_counted(self):
+        """Add the device->host transfers of the outermost engine call to
+        ``report.host_syncs``; a nested call (a bucket executed inside an
+        IRD overlap) is already counted by the call around it."""
+        if self._sync_mark is not None:
+            yield
+            return
+        self._sync_mark = trace.host_syncs()
+        try:
+            yield
+        finally:
+            self.report.host_syncs += trace.host_syncs() - self._sync_mark
+            self._sync_mark = None
 
     # ------------------------------------------------------------ batch query
     def query_batch(
@@ -343,7 +368,6 @@ class AdHashEngine:
             self.executor.locality_aware, self.executor.pinned_opt,
             self.placement.local_join_safe,
         )
-        t_all = time.perf_counter()
 
         # an overlapped bucket hitting a genuinely unexecutable query must
         # not abort the control pass mid-workload: the error is deferred and
@@ -412,7 +436,6 @@ class AdHashEngine:
             self.report.comm_cells += qstats.comm_cells
             self.report.history.append((qstats.mode, qstats.comm_cells, dt))
             out.append((rel, qstats))
-        self.report.wall_time_s += time.perf_counter() - t_all
         return out
 
     def stream_control_step(self, q: Query, batcher: WorkloadBatcher,
@@ -434,11 +457,11 @@ class AdHashEngine:
         ``demoted`` flags a PI hit deferred to the distributed route because
         the mesh is degraded (DESIGN §9) — the caller route-tags its stats
         after the bucket executes."""
-        tree = (
-            build_redistribution_tree(q, self.stats, self.heuristic)
-            if self.adaptive else None
-        )
-        matches = self.pattern_index.match(tree) if self.adaptive else None
+        with self._syncs_counted():
+            return self._stream_control_step(q, batcher, tag, overlap)
+
+    def _stream_control_step(self, q, batcher, tag, overlap):
+        tree, matches = self._pi_match(q)
         executed = None
         demoted = False
         if matches is not None and not self.health.degraded:
@@ -473,13 +496,16 @@ class AdHashEngine:
             self.report.n_distributed += 1
         self.report.n_queries += 1
         self.report.comm_cells += qstats.comm_cells
-        self.report.wall_time_s += dt
         self.report.history.append((qstats.mode, qstats.comm_cells, dt))
 
     def execute_bucket(self, bucket, results) -> None:
         """Evaluate one shape bucket and fill its members' result slots
         (``results[tag] = (relation, stats, seconds)`` — any indexable
         container keyed by the tags the bucket was filed under)."""
+        with self._syncs_counted():
+            self._execute_bucket(bucket, results)
+
+    def _execute_bucket(self, bucket, results) -> None:
         t0 = time.perf_counter()
         if len(bucket) == 1:
             rels_stats = [self._run_sequential(bucket, 0)]
@@ -523,9 +549,9 @@ class AdHashEngine:
         splits and replica footprints bit-identically."""
         if not self.adaptive:
             return
-        tree = build_redistribution_tree(q, self.stats, self.heuristic)
-        self.pattern_index.match(tree)  # LRU touch, as in query()
-        self._post_query_adaptivity(tree)
+        with self._syncs_counted():
+            tree, _ = self._pi_match(q)  # LRU touch, as in query()
+            self._post_query_adaptivity(tree)
 
     def _post_query_adaptivity(self, tree, overlap=None) -> None:
         """The single post-query adaptivity hook: heat-map insert, then IRD,
@@ -536,11 +562,12 @@ class AdHashEngine:
         replica rows onto the failed shard (DESIGN §9); they resume — and
         catch up from the accumulated heat-map counts — once the shard
         recovers."""
-        self.heatmap.insert(tree)
-        if self.health.degraded or self.adaptivity_paused:
-            return
-        self._maybe_redistribute(overlap=overlap)
-        self._maybe_rebalance(overlap=overlap)
+        with trace.span("adapt"):
+            self.heatmap.insert(tree)
+            if self.health.degraded or self.adaptivity_paused:
+                return
+            self._maybe_redistribute(overlap=overlap)
+            self._maybe_rebalance(overlap=overlap)
 
     def _maybe_redistribute(self, overlap=None) -> None:
         """Trigger IRD for newly hot patterns.
@@ -558,7 +585,8 @@ class AdHashEngine:
                 continue
             if self.pattern_index.contains(hot.rtree):
                 continue  # already redistributed (peek: no LRU touch)
-            pending = self.ird.redistribute_deferred(hot)
+            with trace.span("ird"):
+                pending = self.ird.redistribute_deferred(hot)
             try:
                 if overlap is not None:
                     overlap()  # IRD collectives overlap this evaluation
@@ -569,7 +597,8 @@ class AdHashEngine:
                 # registered in the ReplicaIndex, and skipping the publish
                 # would orphan them — unevictable, silently inflating the
                 # budget accounting forever
-                storage, ird_stats = pending.finalize()  # barrier first
+                with trace.span("ird_wait"):
+                    storage, ird_stats = pending.finalize()  # barrier first
                 self.pattern_index.insert(hot.rtree, storage)
                 self.report.n_redistributions += 1
                 self.report.ird_comm_cells += ird_stats.comm_cells
@@ -602,7 +631,8 @@ class AdHashEngine:
         plc = self.placement
         if not plc.supports_split or self._split_candidates is None:
             return
-        counts = fetch_global(self.store.counts).astype(np.int64)
+        counts = host_fetch(self.store.counts,
+                            "store_counts").astype(np.int64)
         mean = float(counts.mean())
         if mean <= 0.0 or float(counts.max()) <= self.skew_threshold * mean:
             return

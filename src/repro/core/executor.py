@@ -20,7 +20,7 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 import numpy as np
 
-from . import dsj
+from . import dsj, trace
 from .backend import quantize_capacity
 from .query import O, P, S, Query, TriplePattern, Var
 from .relation import Relation
@@ -499,6 +499,12 @@ class Executor:
         demote to the staged distributed path below — bit-identical
         answers, with the ``"<substrate>-degraded"`` route tag (DESIGN §9).
         """
+        with trace.span("execute") as span:
+            rel, stats = self._execute(query, ordering, join_vars, capacity)
+            trace.tag(span, route=stats.route, mode=stats.mode)
+        return rel, stats
+
+    def _execute(self, query, ordering, join_vars, capacity):
         stats = QueryStats()
         cap = quantize_capacity(capacity or query.capacity)
         q1 = query.patterns[ordering[0]]
@@ -539,7 +545,7 @@ class Executor:
             acc = comm[0]
             for c in comm[1:]:
                 acc = acc + c
-            stats.comm_cells += int(host_fetch(acc))
+            stats.comm_cells += int(host_fetch(acc, "cells"))
 
         if stats.n_dsj == 0:
             stats.mode = "parallel"
@@ -558,6 +564,12 @@ class Executor:
         stage is only accepted once no query drops rows).  Communication is
         accounted per query from the stages' (B,) cell counts.
         """
+        with trace.span("execute", size=consts.shape[0]) as span:
+            rels, stats = self._execute_batch(bplan, consts)
+            trace.tag(span, route=stats[0].route, mode=stats[0].mode)
+        return rels, stats
+
+    def _execute_batch(self, bplan, consts):
         from .batcher import quantize_batch
 
         b = consts.shape[0]
@@ -632,7 +644,7 @@ class Executor:
             acc = comm[0]
             for c in comm[1:]:
                 acc = acc + c
-            cells_np = host_fetch(acc)
+            cells_np = host_fetch(acc, "cells")
             for i in range(b):
                 stats[i].comm_cells += int(cells_np[i])
 
@@ -640,8 +652,8 @@ class Executor:
         out_vars = bplan.steps[-1].out_vars if bplan.steps else bplan.first_vars
         # one host transfer + B views beats 2*B device-slice dispatches by
         # orders of magnitude; results are final, so numpy backing is fine
-        cols_np = host_fetch(rel_cols)
-        valid_np = host_fetch(rel_valid)
+        cols_np = host_fetch(rel_cols, "rows")
+        valid_np = host_fetch(rel_valid, "valid")
         rels = []
         for i in range(b):
             stats[i].mode = mode
@@ -697,8 +709,8 @@ class Executor:
             start = bad
         out_vars = bplan.steps[-1].out_vars if bplan.steps else bplan.first_vars
         cols, valid = rels[-1]
-        cols_np = host_fetch(cols)
-        valid_np = host_fetch(valid)
+        cols_np = host_fetch(cols, "rows")
+        valid_np = host_fetch(valid, "valid")
         rels_out = []
         for i in range(b):
             st = stats[i]

@@ -57,6 +57,7 @@ from .backend import quantize_capacity
 from .heatmap import HotPattern
 from .pattern_index import ReplicaIndex
 from .query import O, S, TriplePattern, Var
+from .substrate import host_total
 from .transform import RTree, TreeEdge, TreeNode
 from .triples import ShardedTripleStore
 
@@ -124,8 +125,9 @@ class PendingRedistribution:
     def finalize(self) -> tuple[dict[int, str | None], IRDStats]:
         if not self._done:
             jax.block_until_ready(self._barrier)
-            self.stats.comm_cells += sum(int(c) for c in self._cells)
-            self.stats.triples_indexed += sum(int(t) for t in self._triples)
+            self.stats.comm_cells += sum(host_total(c) for c in self._cells)
+            self.stats.triples_indexed += sum(host_total(t)
+                                              for t in self._triples)
             self._cells.clear()
             self._triples.clear()
             self._barrier.clear()
@@ -234,9 +236,10 @@ class IncrementalRedistributor:
         for _ in range(_MAX_RETRIES):
             _, valid, total = self.sub.match_rows(self.main, consts, spec, cap,
                                              backend=self.backend)
-            if int(total) <= cap:
+            total = host_total(total)
+            if total <= cap:
                 break
-            cap = quantize_capacity(max(cap * 2, int(total)))
+            cap = quantize_capacity(max(cap * 2, total))
         pending._triples.append(jnp.sum(valid))
 
     # ----------------------------------------------------------- phase 1
@@ -259,9 +262,10 @@ class IncrementalRedistributor:
         for _ in range(_MAX_RETRIES):
             rows, valid, total = self.sub.match_rows(self.main, consts, spec, cap,
                                                 backend=self.backend)
-            if int(total) <= cap:
+            total = host_total(total)
+            if total <= cap:
                 break
-            cap = quantize_capacity(max(cap * 2, int(total)))
+            cap = quantize_capacity(max(cap * 2, total))
         w = self.w
         pspec = self.placement.stage_spec
         ptable = self.placement.device_table()
@@ -280,11 +284,10 @@ class IncrementalRedistributor:
         cap_peer = cap
         for _ in range(_MAX_RETRIES):
             send, svalid, maxw = jax.vmap(per_worker)(rows, valid)
-            if int(jnp.max(maxw)) <= cap_peer:
+            maxw = host_total(maxw)
+            if maxw <= cap_peer:
                 break
-            cap_peer = cap = quantize_capacity(
-                max(cap_peer * 2, int(jnp.max(maxw)))
-            )
+            cap_peer = cap = quantize_capacity(max(cap_peer * 2, maxw))
         recv = jnp.swapaxes(send, 0, 1).reshape(self.w, -1, 3)
         rvalid = jnp.swapaxes(svalid, 0, 1).reshape(self.w, -1)
         diag = jnp.sum(svalid[jnp.arange(w), jnp.arange(w)])
@@ -325,9 +328,10 @@ class IncrementalRedistributor:
         for _ in range(_MAX_RETRIES):
             prows, pvalid, total = self.sub.match_rows(pstore, pconsts, pspec, cap,
                                                   backend=self.backend)
-            if int(total) <= cap:
+            total = host_total(total)
+            if total <= cap:
                 break
-            cap = quantize_capacity(max(cap * 2, int(total)))
+            cap = quantize_capacity(max(cap * 2, total))
 
         # project + dedupe the propagating column
         cap_proj = cap
@@ -335,9 +339,10 @@ class IncrementalRedistributor:
             proj, projv, nuniq = self.sub.project_unique(
                 prows, pvalid, prop_col, cap_proj, backend=self.backend
             )
-            if int(nuniq) <= cap_proj:
+            nuniq = host_total(nuniq)
+            if nuniq <= cap_proj:
                 break
-            cap_proj = quantize_capacity(max(cap_proj * 2, int(nuniq)))
+            cap_proj = quantize_capacity(max(cap_proj * 2, nuniq))
 
         # source column of the child edge: where the parent vertex binds
         src_col = S if edge.parent_is_subject else O
@@ -352,9 +357,10 @@ class IncrementalRedistributor:
                     proj, projv, cap_peer, backend=self.backend,
                     spec=plc_spec, table=plc_table,
                 )
-                if int(maxb) <= cap_peer:
+                maxb = host_total(maxb)
+                if maxb <= cap_peer:
                     break
-                cap_peer = quantize_capacity(max(cap_peer * 2, int(maxb)))
+                cap_peer = quantize_capacity(max(cap_peer * 2, maxb))
             pending._cells.append(cells)
         else:
             recv, rvalid, cells = self.sub.exchange_broadcast(proj, projv)
@@ -368,12 +374,13 @@ class IncrementalRedistributor:
                 self.main, recv, rvalid, consts, spec, src_col,
                 cap_flat, cap_cand, backend=self.backend,
             )
-            if int(maxf) <= cap_flat and int(maxc) <= cap_cand:
+            maxf, maxc = host_total(maxf), host_total(maxc)
+            if maxf <= cap_flat and maxc <= cap_cand:
                 break
-            if int(maxf) > cap_flat:
-                cap_flat = quantize_capacity(max(cap_flat * 2, int(maxf)))
-            if int(maxc) > cap_cand:
-                cap_cand = quantize_capacity(max(cap_cand * 2, int(maxc)))
+            if maxf > cap_flat:
+                cap_flat = quantize_capacity(max(cap_flat * 2, maxf))
+            if maxc > cap_cand:
+                cap_cand = quantize_capacity(max(cap_cand * 2, maxc))
         pending._cells.append(cells)
 
         flat = cand.reshape(self.w, -1, 3)
@@ -423,15 +430,16 @@ class IncrementalRedistributor:
 
         # start near the balanced shard size; retry-double on skew overflow
         cap_peer = quantize_capacity(
-            max(int(jnp.max(main.counts)) // max(w // 2, 1), 1)
+            max(host_total(main.counts) // max(w // 2, 1), 1)
         )
         for _ in range(_MAX_RETRIES):
             send, svalid, maxw = jax.vmap(make_per_worker(cap_peer))(
                 rows, valid
             )
-            if int(jnp.max(maxw)) <= cap_peer:
+            maxw = host_total(maxw)
+            if maxw <= cap_peer:
                 break
-            cap_peer = quantize_capacity(max(cap_peer * 2, int(jnp.max(maxw))))
+            cap_peer = quantize_capacity(max(cap_peer * 2, maxw))
         else:
             raise RuntimeError("rebalance bucketing exceeded retry budget")
 
@@ -464,7 +472,7 @@ class PendingRebalance:
     def finalize(self) -> tuple[ShardedTripleStore, int]:
         if not self._done:
             jax.block_until_ready(self._barrier)
-            self._moved = sum(int(c) for c in self._cells)
+            self._moved = sum(host_total(c) for c in self._cells)
             self._cells.clear()
             self._barrier.clear()
             self._done = True

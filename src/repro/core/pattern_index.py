@@ -22,7 +22,7 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 import numpy as np
 
-from . import dsj
+from . import dsj, trace
 from .backend import quantize_capacity
 from .executor import ExecutorError, QueryStats, _append_plan, _shared_checks
 from .heatmap import EdgeKey
@@ -300,11 +300,11 @@ class ReplicaIndex:
 
     # ------------------------------------------------------------ accounting
     def per_worker_triples(self) -> np.ndarray:
-        from repro.compat import fetch_global
+        from .substrate import host_fetch
 
         tot = np.zeros(self.w, dtype=np.int64)
         for st in self.modules.values():
-            tot += fetch_global(st.counts).astype(np.int64)
+            tot += host_fetch(st.counts, "replica_counts").astype(np.int64)
         return tot
 
     def max_per_worker(self) -> int:
@@ -363,6 +363,11 @@ class ParallelExecutor:
     ) -> tuple[Relation, QueryStats]:
         stats = QueryStats(mode="parallel-replica",
                            route=f"{self.sub.name}-local")
+        with trace.span("execute", route=stats.route, mode=stats.mode):
+            return self._execute(tree, matches, capacity, stats)
+
+    def _execute(self, tree, matches, capacity, stats
+                 ) -> tuple[Relation, QueryStats]:
         capacity = quantize_capacity(capacity)
         pie_of = {id(qe): pie for qe, pie in matches}
         query = tree.query

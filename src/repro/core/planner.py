@@ -22,6 +22,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Callable
 
+from . import trace
 from .backend import quantize_capacity
 from .query import O, P, S, Query, TriplePattern, Var
 from .stats import GlobalStats
@@ -214,6 +215,10 @@ class LocalityAwarePlanner:
 
     # --------------------------------------------------------------- DP loop
     def plan(self, query: Query) -> Plan:
+        with trace.span("plan"):
+            return self._plan(query)
+
+    def _plan(self, query: Query) -> Plan:
         key = tuple((q.s, q.p, q.o) for q in query.patterns)
         cached = self._memo.pop(key, None)
         if cached is None:

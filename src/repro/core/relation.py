@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
 from .query import Var
 
 __all__ = ["Relation"]
@@ -68,11 +69,15 @@ class Relation:
     # ------------------------------------------------------------ host utils
     def to_numpy(self) -> np.ndarray:
         """All valid binding rows concatenated across workers (host-side);
-        works for worker shards spanning processes (fetch_global)."""
+        works for worker shards spanning processes (fetch_global).  A
+        relation still on the device costs one counted host sync."""
         from repro.compat import fetch_global
 
-        cols = fetch_global(self.cols)
-        valid = fetch_global(self.valid)
+        if not isinstance(self.cols, jax.Array):
+            return np.asarray(self.cols)[np.asarray(self.valid)]
+        with trace.sync("relation"):
+            cols = fetch_global(self.cols)
+            valid = fetch_global(self.valid)
         return cols[valid]
 
     def to_set(self) -> set[tuple[int, ...]]:

@@ -62,7 +62,6 @@ path.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager
 from functools import partial
 
 import jax
@@ -72,14 +71,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.compat import fetch_global, shard_map
 
-from . import dsj
+from . import dsj, trace
 from .backend import resolve_backend
 from .relation import Relation
+from .trace import HostSyncTrace, trace_host_syncs
 from .triples import ShardedTripleStore, match_ranges
 
 __all__ = ["Substrate", "SingleDeviceSubstrate", "MeshSubstrate",
            "DistributedSubstrate", "WORKER_AXIS", "host_total",
-           "host_chain_totals", "host_fetch", "trace_host_syncs"]
+           "host_chain_totals", "host_fetch", "HostSyncTrace",
+           "trace_host_syncs"]
 
 WORKER_AXIS = "data"
 
@@ -180,45 +181,11 @@ class Substrate:
 
 # ---------------------------------------------------------------------------
 # Host sync chokepoints.  Every device->host transfer the executor performs
-# funnels through one of the three helpers below, so the roofline audit (and
-# the one-sync-per-warm-query acceptance test) can count actual syncs by
-# installing a trace — no guessing from profiler output.
+# funnels through one of the three helpers below, each counted by
+# ``repro.core.trace.sync`` (``trace_host_syncs`` reads the count around a
+# block), so the roofline audit and the one-sync-per-warm-query acceptance
+# test count actual syncs — no guessing from profiler output.
 # ---------------------------------------------------------------------------
-class HostSyncTrace:
-    """Counter of device->host transfers, installed by ``trace_host_syncs``."""
-
-    def __init__(self) -> None:
-        self.host_transfers = 0
-
-
-_ACTIVE_TRACE: HostSyncTrace | None = None
-
-
-@contextmanager
-def trace_host_syncs():
-    """Count every host transfer issued inside the block.
-
-    Usage::
-
-        with trace_host_syncs() as t:
-            engine.query(q)
-        assert t.host_transfers == 1   # warm fast-path query
-    """
-    global _ACTIVE_TRACE
-    trace = HostSyncTrace()
-    prev = _ACTIVE_TRACE
-    _ACTIVE_TRACE = trace
-    try:
-        yield trace
-    finally:
-        _ACTIVE_TRACE = prev
-
-
-def _note_host_transfer() -> None:
-    if _ACTIVE_TRACE is not None:
-        _ACTIVE_TRACE.host_transfers += 1
-
-
 def host_total(total) -> int:
     """Host-side max of a stage overflow total.
 
@@ -229,8 +196,8 @@ def host_total(total) -> int:
     multi-process mesh the fetch routes through ``fetch_global`` (the
     per-shard vector spans processes); every process performs it in
     lockstep, so the retry decision is replicated by construction."""
-    _note_host_transfer()
-    return int(np.max(fetch_global(total)))
+    with trace.sync("total"):
+        return int(np.max(fetch_global(total)))
 
 
 def host_chain_totals(totals) -> np.ndarray:
@@ -242,15 +209,15 @@ def host_chain_totals(totals) -> np.ndarray:
     the batch, like the sequential batch retry), so the result is always an
     (S,) int vector.  This is THE one device->host transfer of a warm
     fast-path query."""
-    _note_host_transfer()
-    arr = fetch_global(totals)
+    with trace.sync("chain_totals"):
+        arr = fetch_global(totals)
     return arr.reshape(arr.shape[0], -1).max(axis=1)
 
 
-def host_fetch(x) -> np.ndarray:
+def host_fetch(x, what: str = "fetch") -> np.ndarray:
     """Materialize a device array on the host (result/accounting fetch)."""
-    _note_host_transfer()
-    return fetch_global(x)
+    with trace.sync(what):
+        return fetch_global(x)
 
 
 class SingleDeviceSubstrate(Substrate):

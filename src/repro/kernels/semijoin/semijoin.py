@@ -130,5 +130,6 @@ def semijoin_probe(
                                         jnp.int32)] * 2,
         compiler_params=compiler_params(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
+        name="semijoin_probe",
     )(*key_words, *probe_words)
     return lo.reshape(-1)[:m], hi.reshape(-1)[:m]

@@ -56,6 +56,7 @@ import time
 from collections import deque
 from dataclasses import dataclass
 
+from repro.core import trace
 from repro.core.batcher import Bucket, WorkloadBatcher
 from repro.core.engine import AdHashEngine
 from repro.core.executor import ExecutorError
@@ -164,6 +165,10 @@ class ServeLoop:
     # -------------------------------------------------------------- ingress
     def offer(self, req: Request) -> RetryAfter | None:
         """Admit or reject one arriving request (None == admitted)."""
+        with trace.span("offer", rid=req.rid):
+            return self._offer(req)
+
+    def _offer(self, req: Request) -> RetryAfter | None:
         now = self.clock.now()
         if req.arrival_s is None:
             req.arrival_s = now
@@ -350,6 +355,10 @@ class ServeLoop:
 
     def _control(self, req: Request) -> None:
         """One admitted request through the shared control pass."""
+        with trace.span("control", rid=req.rid):
+            self._control_pass(req)
+
+    def _control_pass(self, req: Request) -> None:
         now = self.clock.now()
         if req.deadline_s - self._shed_horizon() <= now:
             self._shed(req, now)   # doomed while at the head of ingress
@@ -379,7 +388,7 @@ class ServeLoop:
                 # overlap callback already charged for bucket execution
                 self.clock.advance(
                     max(0.0, ctrl_s - (self._overlap_spent - spent0)))
-            self._finish(req, rel, qstats, dt, demoted=False)
+            self._finish(req, rel, qstats, dt, demoted=False, dispatched=now)
         else:
             if demoted:
                 self._demoted.add(req.rid)
@@ -396,6 +405,13 @@ class ServeLoop:
 
     def _run_bucket(self, bucket: Bucket, reason: str) -> None:
         """Dispatch one bucket, charge its service time, resolve members."""
+        n = len(bucket)
+        self.report.bucket_sizes[n] = self.report.bucket_sizes.get(n, 0) + 1
+        with trace.span("bucket", size=n, reason=reason, rids=bucket.tags):
+            self._run_bucket_now(bucket, reason)
+
+    def _run_bucket_now(self, bucket: Bucket, reason: str) -> None:
+        dispatched = self.clock.now()
         t0 = time.perf_counter()
         try:
             self.engine.execute_bucket(bucket, self._results)
@@ -426,10 +442,11 @@ class ServeLoop:
             rel, qstats, dt = self._results.pop(rid)
             demoted = rid in self._demoted
             self._demoted.discard(rid)
-            self._finish(req, rel, qstats, dt, demoted=demoted)
+            self._finish(req, rel, qstats, dt, demoted=demoted,
+                         dispatched=dispatched)
 
     def _finish(self, req: Request, rel, qstats, dt: float,
-                demoted: bool) -> None:
+                demoted: bool, dispatched: float) -> None:
         if demoted:
             # tag only — record_served counts demotions by route suffix
             qstats.route = f"{self.engine.substrate.name}-degraded"
@@ -442,7 +459,8 @@ class ServeLoop:
             self.report.late += 1
         self.report.latencies_s.append(latency)
         self._completions.append(
-            ServedResult(req.rid, rel, qstats, now, latency, late))
+            ServedResult(req.rid, rel, qstats, now, latency, late,
+                         dispatched))
 
     def _note_service(self, n: int, charge: float) -> None:
         a = self.cfg.service_ewma
@@ -463,8 +481,10 @@ class ServeLoop:
         self._last_ckpt = now
         self._ckpt_step += 1
         try:
-            self.checkpoint.save_engine_state(self.engine, self.query_log)
-            self.checkpoint.save_adaptivity(self.engine, step=self._ckpt_step)
+            with trace.span("checkpoint", step=self._ckpt_step):
+                self.checkpoint.save_engine_state(self.engine, self.query_log)
+                self.checkpoint.save_adaptivity(self.engine,
+                                                step=self._ckpt_step)
             self.report.checkpoint_saves += 1
         except (OSError, CheckpointCrash):
             self.report.checkpoint_failures += 1

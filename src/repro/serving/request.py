@@ -88,7 +88,11 @@ class SheddedResult:
 
 @dataclass(frozen=True)
 class ServedResult:
-    """An answered request: the relation, its stats, and SLO accounting."""
+    """An answered request: the relation, its stats, and SLO accounting.
+
+    ``dispatched_s`` is the loop-clock time its work started: its control
+    pass for a pattern-index hit (executed inline there), else its bucket's
+    execution; ``dispatched_s - Request.arrival_s`` is its queue wait."""
 
     rid: int
     relation: Relation
@@ -96,6 +100,7 @@ class ServedResult:
     finished_s: float
     latency_s: float
     late: bool = False
+    dispatched_s: float | None = None
 
 
 @dataclass
@@ -120,6 +125,8 @@ class ServeReport:
     flush_drain: int = 0     # force-pops at end-of-stream drain
     flush_overlap: int = 0   # buckets evaluated inside an IRD collective
     adaptivity_deferrals: int = 0  # control steps run with adaptivity paused
+    # buckets dispatched, by member count (singletons included)
+    bucket_sizes: dict[int, int] = field(default_factory=dict)
     checkpoint_saves: int = 0
     checkpoint_failures: int = 0
     brownout_events: list[tuple[float, int]] = field(default_factory=list)
