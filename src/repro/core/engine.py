@@ -53,6 +53,7 @@ class EngineReport:
     n_parallel_replica: int = 0
     n_distributed: int = 0
     comm_cells: int = 0
+    probe_slots: int = 0  # QueryStats.probe_slots, summed
     ird_comm_cells: int = 0
     ird_triples: int = 0
     n_redistributions: int = 0
@@ -287,6 +288,7 @@ class AdHashEngine:
         dt = time.perf_counter() - t0
         self.report.n_queries += 1
         self.report.comm_cells += qstats.comm_cells
+        self.report.probe_slots += qstats.probe_slots
         self.report.history.append((qstats.mode, qstats.comm_cells, dt))
         return rel, qstats
 
@@ -434,6 +436,7 @@ class AdHashEngine:
                 self.report.n_distributed += 1
             self.report.n_queries += 1
             self.report.comm_cells += qstats.comm_cells
+            self.report.probe_slots += qstats.probe_slots
             self.report.history.append((qstats.mode, qstats.comm_cells, dt))
             out.append((rel, qstats))
         return out
@@ -496,6 +499,7 @@ class AdHashEngine:
             self.report.n_distributed += 1
         self.report.n_queries += 1
         self.report.comm_cells += qstats.comm_cells
+        self.report.probe_slots += qstats.probe_slots
         self.report.history.append((qstats.mode, qstats.comm_cells, dt))
 
     def execute_bucket(self, bucket, results) -> None:

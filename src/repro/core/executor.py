@@ -15,6 +15,7 @@ cells are accumulated into QueryStats — the paper's communication metric.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 import jax.numpy as jnp
@@ -31,10 +32,13 @@ __all__ = ["QueryStats", "Executor", "ExecutorError"]
 
 _MAX_RETRIES = 7
 
-# Up to this capacity class a projection keeps its full width, so small
-# queries keep one shape per class; above it the exchange is cut to the
-# class of the live prefix.
-_PROJ_FLOOR = 1 << 12
+# The narrowest class a projection is cut to.  The probe searches every
+# slot of the exchange, padding included, so the floor is what a selective
+# step pays.  At LUBM-100 scale 512 holds each DSJ step of LUBM's selective
+# templates in one class, so each keeps one compiled width: Q7 projects one
+# professor's few courses, Q12 every department (about 2,000 over 8
+# workers).
+_PROJ_FLOOR = 1 << 9
 
 
 def _live_projection(proj, pvalid, n_live: int, cap_proj: int):
@@ -63,6 +67,10 @@ class QueryStats:
     n_dsj: int = 0
     n_local_joins: int = 0
     n_retries: int = 0
+    # slots the DSJ probe-and-reply stages searched, valid or padding:
+    # W x senders x exchange width per call, retries included (static
+    # shapes, so counting costs no host sync)
+    probe_slots: int = 0
     plan: list[str] = field(default_factory=list)
     # which substrate route executed the query: "" for the distributed
     # shard_map wrappers, "<substrate>-local" when a PI hit took the
@@ -333,6 +341,7 @@ class Executor:
                 self.store, recv, rvalid, consts, spec, c2, cap_flat, cap_cand,
                 backend=self.backend,
             )
+            stats.probe_slots += math.prod(recv.shape)
             mf, mc = host_total(maxf), host_total(maxc)
             if mf <= cap_flat and mc <= cap_cand:
                 break
@@ -790,6 +799,8 @@ class Executor:
                 self.store, recv, rvalid, qc, sp.spec, sp.c2, cap_flat,
                 cap_cand, backend=self.backend,
             )
+            for st in stats:
+                st.probe_slots += math.prod(recv.shape[1:])
             mf, mc = host_total(maxf), host_total(maxc)
             if mf <= cap_flat and mc <= cap_cand:
                 break

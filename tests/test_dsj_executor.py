@@ -161,6 +161,7 @@ def test_wide_projection_is_cut_to_its_live_prefix(monkeypatch):
     class of its live prefix; answers, sequential and batched, are those
     of the brute-force matcher."""
     from repro.core import executor as ex
+    from repro.core.backend import quantize_capacity
     from repro.core.engine import AdHashEngine
     from repro.data.synthetic_rdf import lubm_like, lubm_queries
 
@@ -169,7 +170,7 @@ def test_wide_projection_is_cut_to_its_live_prefix(monkeypatch):
 
     def recording(proj, pvalid, n_live, cap_proj):
         out = cut(proj, pvalid, n_live, cap_proj)
-        widths.append((cap_proj, out[2], proj.shape[-1], out[0].shape[-1]))
+        widths.append((cap_proj, out[2], n_live, out[0].shape[-1]))
         return out
 
     monkeypatch.setattr(ex, "_live_projection", recording)
@@ -186,9 +187,10 @@ def test_wide_projection_is_cut_to_its_live_prefix(monkeypatch):
     for q, (rel, _) in zip(queries, eng.query_batch(queries + queries)):
         assert set(map(tuple, rel.project_to(q.vars).tolist())) == \
             match_query(triples, q), q.name
-    assert widths and all(new == ex._PROJ_FLOOR < old == 1 << 14
-                          and shp_new == new
-                          for old, new, _, shp_new in widths)
+    assert widths and all(
+        new == quantize_capacity(n_live, floor=ex._PROJ_FLOOR) < old == 1 << 14
+        and shp_new == new
+        for old, new, n_live, shp_new in widths)
 
 
 def test_live_cut_compiles_once_per_class(monkeypatch):
@@ -243,3 +245,50 @@ def test_live_cut_compiles_once_per_class(monkeypatch):
                 assert be.probe_compile_cache_size() == before, (pass_, width)
             seen.add(width)
     assert 3 <= len(seen) <= (cap // 4).bit_length()
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_narrow_floor_keeps_answers_and_accounting(monkeypatch, batched):
+    """Q7- and Q12-shaped DSJ queries with the projection floor at 512
+    answer and account exactly as a twin engine with the floor at 4,096:
+    the padding past the live prefix carries no valid value.  Only the
+    probe slots searched shrink, 8x for steps under 512 live values, and
+    counting them costs no host sync."""
+    from repro.core import executor as ex
+    from repro.core.engine import AdHashEngine
+    from repro.data.synthetic_rdf import lubm_like, lubm_queries
+
+    d, triples = lubm_like(2, 2, 2, 3, 2, seed=3)
+    tpl = lubm_queries(d)
+    queries = [tpl["q7"].make(p) for p in tpl["q7"].constants[:6]] + \
+        [tpl["q12"].make(u) for u in tpl["q12"].constants]
+    w, floor = 4, ex._PROJ_FLOOR
+
+    def serve(floor):
+        monkeypatch.setattr(ex, "_PROJ_FLOOR", floor)
+        eng = AdHashEngine(triples, w, dictionary=d, adaptive=False,
+                           capacity=1 << 12)
+        if batched:
+            out = eng.query_batch(queries)
+        else:
+            out = [eng.query(q) for q in queries]
+        return eng.report, [(rel.project_to(q.vars), st)
+                            for q, (rel, st) in zip(queries, out)]
+
+    wide_report, wide = serve(1 << 12)
+    narrow_report, narrow = serve(floor)
+    for q, (rows_w, st_w), (rows_n, st_n) in zip(queries, wide, narrow):
+        assert np.array_equal(rows_n, rows_w), q.name
+        assert set(map(tuple, rows_n.tolist())) == match_query(triples, q)
+        assert st_n.n_dsj >= 1, q.name
+        for key in ("comm_cells", "n_retries", "mode", "route", "n_dsj"):
+            assert getattr(st_n, key) == getattr(st_w, key), (q.name, key)
+        # every step here holds far fewer than 512 live values
+        assert st_n.probe_slots == st_n.n_dsj * w * w * floor == \
+            st_n.n_dsj * w * w * 512, q.name
+        assert st_w.probe_slots == 8 * st_n.probe_slots, q.name
+    assert narrow_report.probe_slots == sum(st.probe_slots
+                                            for _, st in narrow)
+    assert wide_report.probe_slots == 8 * narrow_report.probe_slots
+    assert narrow_report.comm_cells == wide_report.comm_cells
+    assert narrow_report.host_syncs == wide_report.host_syncs > 0
